@@ -8,12 +8,9 @@ threefry lowering does not guarantee that: a ``[rows, d]`` normal draw
 materialized under a ``P('model', None)`` out-sharding produces different
 bits on a (2, 4) mesh than on (1, 1), which made parameter initialization
 mesh-dependent and broke cross-mesh loss parity for every sync scheme
-(dense included).  Newer jax releases default the flag on; pinning it here
-makes the pinned CI leg (jax 0.4.x) behave identically to latest.
+(dense included).  Current jax releases default the flag on; setting it
+here keeps the contract explicit.
 """
 import jax
 
-try:
-    jax.config.update("jax_threefry_partitionable", True)
-except Exception:  # pragma: no cover — flag retired once always-on upstream
-    pass
+jax.config.update("jax_threefry_partitionable", True)

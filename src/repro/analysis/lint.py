@@ -46,16 +46,6 @@ def _ensure_host_devices() -> None:
             flags + " --xla_force_host_platform_device_count=8").strip()
 
 
-def _shard_map():
-    import jax
-    try:
-        sm = jax.shard_map
-        return sm, {"check_vma": False}
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-        return sm, {"check_rep": False}
-
-
 def _payload(M: int, n: int, density: float):
     """Per-worker [n, M] grads: identical support on every worker (claims
     stay device-symmetric), distinct dyadic values (sums are exact)."""
@@ -151,7 +141,6 @@ def build_flat_subject(
     if findings:
         return None, findings
     args, exp_words = _stage_setup(spec, M, n, overrides=overrides)
-    sm, smkw = _shard_map()
     mesh = Mesh(np.asarray(jax.devices()[:n]), ("data",))
 
     def local(v):
@@ -159,8 +148,9 @@ def build_flat_subject(
                                      stage_args=args)
         return out, st.sent_words[None], st.overflow[None]
 
-    mapped = sm(local, mesh=mesh, in_specs=P("data"),
-                out_specs=(P(), P("data"), P("data")), **smkw)
+    mapped = jax.shard_map(local, mesh=mesh, in_specs=P("data"),
+                           out_specs=(P(), P("data"), P("data")),
+                           check_vma=False)
     g = jnp.asarray(_payload(M, n, spec.lint_density))
     words, stablehlo, hlo, findings = _run_and_lower(
         jax.jit(mapped), g, label)
@@ -205,7 +195,6 @@ def build_hier_subject(
         stage_kw[li] = args
         wire[lvl.size] = exp_words  # group sizes distinct (2 vs n//2)
     n_intra, n_inter = topo.intra.size, topo.inter.size
-    sm, smkw = _shard_map()
     mesh = Mesh(np.asarray(jax.devices()[:n]).reshape(n_inter, n_intra),
                 (tp.DP_INTER, tp.DP_INTRA))
 
@@ -216,8 +205,8 @@ def build_hier_subject(
         return out, lv[None, None], st.overflow[None, None]
 
     spec2 = P(tp.DP_INTER, tp.DP_INTRA)
-    mapped = sm(local, mesh=mesh, in_specs=spec2,
-                out_specs=(P(), spec2, spec2), **smkw)
+    mapped = jax.shard_map(local, mesh=mesh, in_specs=spec2,
+                           out_specs=(P(), spec2, spec2), check_vma=False)
     g = jnp.asarray(_payload(M, n, spec.lint_density)
                     ).reshape(n_inter, n_intra, M)
     by_level, stablehlo, hlo, findings = _run_and_lower(
@@ -266,7 +255,6 @@ def build_schedule_subject(
                                           0, M),),
                        nbytes=M * WORD)
              for i in range(nb)]
-    sm, smkw = _shard_map()
     mesh = Mesh(np.asarray(jax.devices()[:n]), ("data",))
 
     def local(v):  # [1, nb, M]
@@ -284,8 +272,9 @@ def build_schedule_subject(
         ov = sum(st.overflow for st in stats)
         return jnp.stack(outs), words[None], ov[None]
 
-    mapped = sm(local, mesh=mesh, in_specs=P("data"),
-                out_specs=(P(), P("data"), P("data")), **smkw)
+    mapped = jax.shard_map(local, mesh=mesh, in_specs=P("data"),
+                           out_specs=(P(), P("data"), P("data")),
+                           check_vma=False)
     base = _payload(M, n, density)
     g = jnp.asarray(np.stack([base * (1 + b / 16.0) for b in range(nb)],
                              axis=1))  # [n, nb, M]

@@ -72,26 +72,6 @@ class SyncStats(NamedTuple):
     by_level: tuple = ()     # per-level f32 wire words (hier plans only)
 
 
-def _axis_size(axis: str) -> int:
-    """Size of a named axis as a static python int — axis sizing must
-    never emit a collective.
-
-    ``lax.axis_size`` (newer jax) is the public spelling.  On the pinned
-    0.4.x CI leg it does not exist; there ``jax.core.axis_frame(axis)``
-    resolves the size from the trace-time axis env (returning either the
-    int itself or a frame carrying ``.size``, depending on the release).
-    ``psum(1, axis)`` stays as the last-resort fallback — jax folds a
-    non-tracer operand statically, so even that path is collective-free,
-    which tests/test_hier_schemes.py asserts on lowered HLO."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    try:
-        frame = jax.core.axis_frame(axis)
-        return int(getattr(frame, "size", frame))
-    except Exception:
-        return lax.psum(1, axis)
-
-
 def _nnz(idx: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum((idx != EMPTY).astype(jnp.float32))
 
@@ -156,7 +136,7 @@ def _coo_reduce(
 
 def dense_sync(dense: jnp.ndarray, *, axis: str) -> tuple[jnp.ndarray, SyncStats]:
     """Ring allreduce (Horovod's AllReduce in the paper's evaluation)."""
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     out = lax.psum(dense, axis)
     words = jnp.float32(2 * (n - 1) / n) * dense.size
     return out, SyncStats(sent_words=words, overflow=jnp.int32(0))
@@ -174,7 +154,7 @@ def agsparse_sync(
     all_idx = lax.all_gather(coo.indices, axis)   # [n, C]
     all_val = lax.all_gather(coo.values, axis)    # [n, C(, d)]
     out = _coo_reduce(jnp.zeros_like(dense), all_idx, all_val)
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     sent = (n - 1) * _nnz(coo.indices) * (1 + _vwidth(dense))
     return out, SyncStats(sent_words=sent, overflow=coo.overflow)
 

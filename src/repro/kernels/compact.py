@@ -15,8 +15,11 @@ deliberately trading ops for full vectorization, which wins for the r1+r2 ~
 that (the jnp path in ``hashing.row_compact`` stays O(L); prefer it if rows
 get long).  Integer adds are exact, so no MXU/f32 precision concerns apply.
 
-Layout: mem [R, L] int32; grid (R, L / BLOCK_J); each step reads a full row
-and writes one BLOCK_J-wide output tile.
+Layout: mem [R, L] int32 viewed as [R, 1, L]; grid (R, L / BLOCK_J); each
+step reads a full row (a squeezed ``(None, 1, L)`` block, so the block's
+last two dims equal the array's — the TPU tiling rule) and writes one
+BLOCK_J-wide output tile.  The prefix rank is ``prefix.prefix_count``
+(Mosaic has no cumsum).
 """
 from __future__ import annotations
 
@@ -27,6 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.hashing import EMPTY
+from repro.kernels.prefix import column, prefix_count
 
 BLOCK_J = 128
 
@@ -34,15 +38,14 @@ BLOCK_J = 128
 def _kernel(mem_ref, out_ref):
     row = mem_ref[...]                                   # [1, L] int32
     valid = row != EMPTY
-    inc = valid.astype(jnp.int32)
-    pos = jnp.cumsum(inc, axis=1) - 1                    # prefix rank per entry
-    nnz = jnp.sum(inc)
+    pos = prefix_count(valid) - 1                        # prefix rank per entry
+    nnz = jnp.sum(valid.astype(jnp.int32))
     j0 = pl.program_id(1) * BLOCK_J
     jcol = j0 + jax.lax.broadcasted_iota(jnp.int32, (1, BLOCK_J), 1)  # [1, BJ]
     # hit[i, j]: live entry i lands in output column j
-    hit = valid[0, :, None] & (pos[0, :, None] == jcol[0, None, :])   # [L, BJ]
-    vals = jnp.sum(jnp.where(hit, row[0, :, None], 0), axis=0)        # [BJ]
-    out_ref[...] = jnp.where(jcol < nnz, vals[None, :], EMPTY)
+    hit = column(valid) & (column(pos) == jcol)                       # [L, BJ]
+    vals = jnp.sum(jnp.where(hit, column(row), 0), axis=0, keepdims=True)
+    out_ref[...] = jnp.where(jcol < nnz, vals, EMPTY)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -53,8 +56,8 @@ def row_compact(mem: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
     return pl.pallas_call(
         _kernel,
         grid=(R, L // BLOCK_J),
-        in_specs=[pl.BlockSpec((1, L), lambda i, j: (i, 0))],
-        out_specs=pl.BlockSpec((1, BLOCK_J), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((R, L), jnp.int32),
+        in_specs=[pl.BlockSpec((None, 1, L), lambda i, j: (i, 0, 0))],
+        out_specs=pl.BlockSpec((None, 1, BLOCK_J), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((R, 1, L), jnp.int32),
         interpret=interpret,
-    )(mem)
+    )(mem[:, None, :])[:, 0, :]

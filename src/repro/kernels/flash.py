@@ -35,8 +35,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bk: int, causal: bool,
 
     def body(ci, carry):
         m, l, o = carry
-        k = pl.load(k_ref, (pl.dslice(ci * bk, bk), slice(None)))
-        v = pl.load(v_ref, (pl.dslice(ci * bk, bk), slice(None)))
+        k = k_ref[pl.ds(ci * bk, bk), :]
+        v = v_ref[pl.ds(ci * bk, bk), :]
         s = q @ k.astype(jnp.float32).T                      # [BQ, bk] VMEM
         pos_k = ci * bk + jax.lax.broadcasted_iota(
             jnp.int32, (1, bk), 1)[0]

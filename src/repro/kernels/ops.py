@@ -83,11 +83,7 @@ def coo_scatter_add_op(out: jnp.ndarray, idx: jnp.ndarray, vals: jnp.ndarray,
                        *, interpret: bool | None = None):
     """out [M, d] += vals [C, d] at row idx [C] (EMPTY dropped)."""
     interpret = _resolve(interpret)
-    C = idx.shape[0]
-    pad = (-C) % _sa.BLOCK_C
-    idxp = jnp.pad(idx, (0, pad), constant_values=EMPTY)
-    valsp = jnp.pad(vals, ((0, pad), (0, 0)))
-    return _sa.coo_scatter_add(out, idxp, valsp, interpret=interpret)
+    return _sa.coo_scatter_add(out, idx, vals, interpret=interpret)
 
 
 def batched_coo_reduce_op(out: jnp.ndarray, idx: jnp.ndarray,
@@ -299,12 +295,8 @@ def zen_commit_push_fused_op(lp: jnp.ndarray, vals: jnp.ndarray, *,
         lpos, v, bm, ov = _zen_commit_push_fused_xla(
             lp, vals2, cap_server, cap_pull)
     else:
-        C = lp.shape[0]
-        pad = (-C) % _zc.BLOCK_C
-        lpp = jnp.pad(lp, (0, pad), constant_values=EMPTY)
-        vp = jnp.pad(vals2, ((0, pad), (0, 0)))
         lpos, v, bm, ov = _zc.zen_commit_push_fused(
-            lpp[None, :], vp, cap_server=cap_server, cap_pull=cap_pull,
+            lp, vals2, cap_server=cap_server, cap_pull=cap_pull,
             interpret=interpret)
         W = -(-cap_server // BITS)
         lpos, v = lpos[0, :cap_pull], v[:cap_pull]

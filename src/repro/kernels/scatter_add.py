@@ -3,12 +3,23 @@
 The Pull-side hot loop of Zen: accumulate pushed (index, row-value) pairs
 into the server's compact partition buffer.  On GPU this is atomicAdd; on
 TPU the *sequential* grid makes read-modify-write race-free, so the kernel
-is a plain RMW loop over the tile's entries — the TPU-idiomatic equivalent
+is a plain RMW loop over the entries — the TPU-idiomatic equivalent
 (DESIGN.md §3).
 
-The output buffer is aliased with an input (in-place accumulation); the
-value width d should be lane-aligned (multiples of 128) for real-TPU
-efficiency; interpret-mode validation accepts any d.
+Layout: the indices are scalar-prefetched into SMEM (the scalar core reads
+one per iteration); the values ``[C, d]`` sit in VMEM once; the output is
+tiled into ``BLOCK_M``-row blocks, and each grid step walks the whole
+index stream in order and applies the entries that land in its block.
+Per output row the adds therefore happen in stream order — XLA's
+scatter-add order — so the result is bit-identical to it.  Accumulation
+runs in an f32 scratch rounded back to the output dtype after every add
+(bf16 rows cannot be addressed one at a time; an f32 add of two bf16
+values rounded to bf16 is the bf16 add).
+
+The result is a new buffer: the kernel does not alias ``out`` (an
+aliased operand that is a caller's live array would need a copy anyway,
+and reading the accumulation base from the input block keeps the
+kernel's view of it well defined).
 """
 from __future__ import annotations
 
@@ -17,27 +28,35 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hashing import EMPTY
 
-BLOCK_C = 256
+BLOCK_M = 512  # output rows per grid step
 
 
-def _kernel(idx_ref, vals_ref, out_in_ref, out_ref, *, rows: int):
-    # out_ref aliases out_in_ref (input_output_aliases) and starts with its
-    # contents; all RMW goes through out_ref.
-    del out_in_ref
-    def body(i, _):
+def _kernel(idx_ref, vals_ref, base_ref, out_ref, acc_ref, *, rows: int):
+    # an output block starts as whatever VMEM held on the chip: the
+    # block's starting contents come from the input block
+    bm = acc_ref.shape[0]
+    lo = pl.program_id(0) * bm
+    acc_ref[...] = base_ref[...].astype(jnp.float32)
+
+    def body(i, carry):
         ix = idx_ref[i]
-        ok = (ix != EMPTY) & (ix < rows) & (ix >= 0)
-        safe = jnp.where(ok, ix, 0)
-        row = pl.load(out_ref, (pl.dslice(safe, 1), slice(None)))
-        val = vals_ref[i, :][None, :]
-        upd = row + jnp.where(ok, val, 0).astype(row.dtype)
-        pl.store(out_ref, (pl.dslice(safe, 1), slice(None)), upd)
-        return 0
+        local = ix - lo
+
+        @pl.when((ix != EMPTY) & (ix >= 0) & (ix < rows)
+                 & (local >= 0) & (local < bm))
+        def _():
+            upd = acc_ref[pl.ds(local, 1), :] + vals_ref[pl.ds(i, 1), :]
+            acc_ref[pl.ds(local, 1), :] = upd.astype(out_ref.dtype).astype(
+                jnp.float32)
+
+        return carry
 
     jax.lax.fori_loop(0, idx_ref.shape[0], body, 0)
+    out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -49,18 +68,22 @@ def coo_scatter_add(out: jnp.ndarray, idx: jnp.ndarray, vals: jnp.ndarray,
     """
     C = idx.shape[0]
     M, d = out.shape
-    bc = min(BLOCK_C, C)
-    assert C % bc == 0
-    return pl.pallas_call(
+    bm = min(BLOCK_M, -(-M // 8) * 8)
+    pad = (-M) % bm
+    outp = jnp.pad(out, ((0, pad), (0, 0)))
+    res = pl.pallas_call(
         functools.partial(_kernel, rows=M),
-        grid=(C // bc,),
-        in_specs=[
-            pl.BlockSpec((bc,), lambda i: (i,)),
-            pl.BlockSpec((bc, d), lambda i: (i, 0)),
-            pl.BlockSpec((M, d), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((M, d), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((M, d), out.dtype),
-        input_output_aliases={2: 0},
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(outp.shape[0] // bm,),
+            in_specs=[
+                pl.BlockSpec((C, d), lambda i, ix: (0, 0)),
+                pl.BlockSpec((bm, d), lambda i, ix: (i, 0)),
+            ],
+            out_specs=pl.BlockSpec((bm, d), lambda i, ix: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((bm, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(outp.shape, out.dtype),
         interpret=interpret,
-    )(idx, vals, out)
+    )(idx.astype(jnp.int32), vals.astype(jnp.float32), outp)
+    return res[:M]

@@ -90,8 +90,6 @@ def dryrun_combo(arch: str, shape: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax 0.4.x: [dict], newer: dict
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     from repro.launch import hlo_cost
     walked = hlo_cost.analyze(

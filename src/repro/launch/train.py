@@ -25,6 +25,7 @@ from repro.core.registry import cli_scheme_choices
 from repro.core.sparsify import DensityController
 from repro.core.zen import SyncConfig
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.common import make_ctx
 from repro.optim.optimizers import OptConfig
@@ -93,6 +94,10 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    cache_dir = enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"platform={dev.platform} device_kind={dev.device_kind} "
+          f"devices={len(jax.devices())} compile_cache={cache_dir}")
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -155,19 +160,29 @@ def main():
 
     data = iter(SyntheticLM(cfg, DataConfig(
         seq_len=args.seq_len, batch=args.global_batch, seed=args.seed)))
-    t0 = time.time()
+    # the tok/s window opens after step 0, whose time is mostly the
+    # compile; step 0 is timed and reported on its own
+    t0 = time.perf_counter()
     tokens_done = 0
     for step in range(args.steps):
         b = next(data)
         batch = {k: jnp.asarray(v) for k, v in b.items()}
         params, opt, m = prog.train_step(params, opt, batch)
-        tokens_done += args.global_batch * args.seq_len
+        if step == 0:
+            jax.block_until_ready(m["loss"])
+            print(f"step 0 (compile + first step) "
+                  f"{time.perf_counter() - t0:.2f}s")
+            t0 = time.perf_counter()
+        else:
+            tokens_done += args.global_batch * args.seq_len
         if step % args.log_every == 0 or step == args.steps - 1:
             jax.block_until_ready(m["loss"])
-            dt = time.time() - t0
-            print(f"step {step:5d} loss={float(m['loss']):.4f} "
-                  f"tok/s={tokens_done / dt:,.0f} "
-                  f"sparse_words={float(m['sync/sparse_sent_words']):,.0f} "
+            dt = time.perf_counter() - t0
+            rate = (f"tok/s={tokens_done / dt:,.0f} " if tokens_done
+                    else "")
+            print(f"step {step:5d} loss={float(m['loss']):.4f} {rate}"
+                  f"sparse_words="
+                  f"{float(m['sync/sparse_sent_words']):,.0f} "
                   f"overflow={int(float(m['sync/overflow']))}")
         if controller is not None and step % args.log_every == 0:
             controller.observe(

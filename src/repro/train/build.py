@@ -20,18 +20,6 @@ from repro.train import steps as st
 from repro.train.steps import TrainerConfig
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map on new jax; jax.experimental.shard_map (check_rep
-    spelling) on older releases."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 @dataclasses.dataclass
 class Program:
     """A compiled-able distributed program bundle for one architecture."""
@@ -144,7 +132,7 @@ def attach_train(prog: Program, seq_len: int, global_batch: int,
     step_fn = st.make_train_step(model, tcfg, prog.param_specs,
                                  gradsync=prog.gradsync)
     metric_specs = P()
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         step_fn, mesh=mesh,
         in_specs=(prog.param_specs, ospecs, bspecs),
         out_specs=(prog.param_specs, ospecs, metric_specs),
@@ -167,7 +155,7 @@ def attach_serve(prog: Program, seq_len: int, global_batch: int,
         bspecs = st.batch_pspecs(bshapes, ctx, n_shards)
         cspecs = st.cache_pspecs(model)
         fn = st.make_prefill_step(model)
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             fn, mesh=mesh, in_specs=(prog.param_specs, bspecs),
             out_specs=(P(bspecs["tokens"][0], "model"), cspecs),
             check_vma=False)
@@ -188,7 +176,7 @@ def attach_serve(prog: Program, seq_len: int, global_batch: int,
     global_cache = st.globalize_cache(local_cache, cspecs, mesh)
     fn = st.make_decode_step(model, window=window)
     tok_spec = bspecs["tokens"]
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(prog.param_specs, cspecs, tok_spec),
         out_specs=(tok_spec, P(tok_spec[0]), cspecs),

@@ -307,26 +307,19 @@ def test_run_schedule_intra_stage_value_identity():
 
 
 def test_axis_size_emits_no_collective():
-    """_axis_size must resolve statically: a lowered dense_sync contains
-    exactly ONE all-reduce (the gradient psum), not a second one for the
-    worker count."""
+    """lax.axis_size must resolve statically: a lowered dense_sync
+    contains exactly ONE all-reduce (the gradient psum), not a second one
+    for the worker count."""
     from jax.sharding import PartitionSpec as P
 
     n = jax.device_count()
     mesh = jax.make_mesh((n,), ("data",))
-    try:
-        sm = jax.shard_map
-        kw = dict(check_vma=False)
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-        kw = dict(check_rep=False)
-
     def f(v):
         out, st = schemes.dense_sync(v[0], axis="data")
         return out, st.sent_words
 
-    g = sm(f, mesh=mesh, in_specs=P("data"),
-           out_specs=(P(), P()), **kw)
+    g = jax.shard_map(f, mesh=mesh, in_specs=P("data"),
+                      out_specs=(P(), P()), check_vma=False)
     hlo = jax.jit(g).lower(
         jnp.ones((n, 64))).compile().as_text()
     # zenlint's parsed-HLO counter: async start/done pairs count once

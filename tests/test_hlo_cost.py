@@ -53,10 +53,7 @@ def test_xla_cost_analysis_undercounts_scans():
     x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
     ws = jax.ShapeDtypeStruct((10, 64, 64), jnp.float32)
     comp = _compile(scanned, x, ws)
-    ca = comp.cost_analysis()
-    if isinstance(ca, list):  # older jax returns one dict per computation
-        ca = ca[0] if ca else {}
-    xla = ca.get("flops", 0.0)
+    xla = comp.cost_analysis().get("flops", 0.0)
     walker = hlo_cost.analyze(comp.as_text())["flops"]
     assert walker > 5 * xla  # XLA counts the body once
 
