@@ -110,6 +110,17 @@ class BucketPlan:
     def schemes(self) -> tuple[str, ...]:
         return tuple(b.scheme for b in self.buckets)
 
+    def counts(self) -> dict[str, int]:
+        """The plan's bucket counts: ``n_buckets``, ``compressed_buckets``
+        and ``buckets[<scheme>]`` per resolved scheme.  Facts of the plan,
+        fixed before the first step, so no step reports them."""
+        out = {"n_buckets": len(self.buckets),
+               "compressed_buckets": sum(b.compress != "none"
+                                         for b in self.buckets)}
+        for scheme in sorted(set(self.schemes)):
+            out[f"buckets[{scheme}]"] = self.schemes.count(scheme)
+        return out
+
     def validate(self) -> None:
         """Every leaf in exactly one bucket; sparse buckets are singletons;
         fused dense buckets respect the byte budget (oversized leaves may
@@ -248,9 +259,9 @@ def reduce_stats(
     """Reduce per-bucket SyncStats into the trainer's metric dict.
 
     Keeps the monolithic path's keys (sparse_sent_words / overflow /
-    dense_words) so dashboards and the multi-device tests are unchanged,
-    and adds per-scheme bucket tags — static plan facts reported as
-    constants so they survive the pmean over data.  ``dense_words``
+    dense_words) so dashboards and the multi-device tests are unchanged;
+    the bucket counts are facts of the plan (``BucketPlan.counts``), not
+    of the step, and are not reported here.  ``dense_words``
     counts the fused-psum buckets; everything synchronized with a sparse
     scheme — row-sparse leaves AND compressed dense buckets — lands in
     ``sparse_sent_words`` (for uncompressed plans the split is identical
@@ -260,8 +271,6 @@ def reduce_stats(
     sent = jnp.float32(0.0)
     dense_words = jnp.float32(0.0)
     overflow = jnp.int32(0)
-    tags: dict[str, int] = {}
-    n_compressed = 0
     level_words: list = []
     for b, st in zip(plan.buckets, per_bucket):
         overflow = overflow + st.overflow
@@ -269,8 +278,6 @@ def reduce_stats(
             sent = sent + st.sent_words
         else:
             dense_words = dense_words + st.sent_words
-        tags[b.scheme] = tags.get(b.scheme, 0) + 1
-        n_compressed += b.compress != "none"
         # hierarchical plans tag wire words by topology level (fastest
         # first); accumulate a whole-step per-level split
         for i, w in enumerate(getattr(st, "by_level", ()) or ()):
@@ -281,14 +288,9 @@ def reduce_stats(
         "sync/sparse_sent_words": sent,
         "sync/overflow": overflow,
         "sync/dense_words": dense_words,
-        "sync/n_buckets": jnp.float32(len(plan.buckets)),
     }
     if len(level_words) >= 2:
         stats["sync/intra_words"] = level_words[0]
         stats["sync/inter_words"] = level_words[-1]
-    if n_compressed:
-        stats["sync/compressed_buckets"] = jnp.float32(n_compressed)
-    for scheme, count in sorted(tags.items()):
-        stats[f"sync/buckets[{scheme}]"] = jnp.float32(count)
     stats.update(extra or {})
     return stats

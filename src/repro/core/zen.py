@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.analysis import scopes
 from repro.core import buckets as bk
 from repro.core import costmodel, schemes, sparsify
 from repro.core import topology as tpg
@@ -311,6 +312,7 @@ class GradSync:
             use_hash_bitmap=cfg.use_hash_bitmap, backend=cfg.backend,
             fused=cfg.fused_encode, fused_commit=cfg.fused_commit)
 
+    @jax.named_scope(scopes.SYNC_ENCODE)
     def _encode_bucket(self, bucket: bk.Bucket, payload: jnp.ndarray):
         """Local, collective-free stage (overlappable with the previous
         bucket's wire time).  Buckets whose FIRST plan stage is Zen
@@ -346,6 +348,7 @@ class GradSync:
         return schemes.stage_sync(stage.scheme, g, axis=lvl.axis,
                                   n=lvl.size, stage_args=args)
 
+    @jax.named_scope(scopes.SYNC_EXCHANGE)
     def _intra_bucket(self, bucket: bk.Bucket, enc):
         """Hierarchical stage 0: aggregate over the fast (intra) axis.
         Only wired into the schedule on two-level topologies — the
@@ -356,6 +359,7 @@ class GradSync:
         g1, st = self._run_stage(bucket, 0, g, enc=zen_enc)
         return (g1, st)
 
+    @jax.named_scope(scopes.SYNC_EXCHANGE)
     def _commit_bucket(
         self, bucket: bk.Bucket, enc
     ) -> tuple[jnp.ndarray, SyncStats]:
@@ -415,8 +419,9 @@ class GradSync:
                 key = jax.random.fold_in(jax.random.fold_in(
                     jax.random.PRNGKey(ccfg.seed), bucket.bid), step)
             r = residual[bucket.key] if ccfg.ef else None
-            sent, r_new, d1 = sparsify.compress_bucket(
-                ccfg, payload, r, key=key)
+            with jax.named_scope(scopes.SYNC_ENCODE):
+                sent, r_new, d1 = sparsify.compress_bucket(
+                    ccfg, payload, r, key=key)
             if r_new is not None:
                 new_res[bucket.key] = r_new
             extra[sparsify.DENSITY1_KEY.format(key=bucket.key)] = d1
@@ -449,7 +454,8 @@ class GradSync:
         compress_fn = (self._compress_hook(residual, step, new_res, extra)
                        if self.compress.enabled else None)
         flat, treedef = jax.tree_util.tree_flatten(grads)
-        payloads = [bk.gather_bucket(b, flat) for b in self.plan.buckets]
+        with jax.named_scope(scopes.SYNC_ENCODE):
+            payloads = [bk.gather_bucket(b, flat) for b in self.plan.buckets]
         outs, per_bucket = schedule.run_schedule(
             self.plan.buckets, payloads,
             self._encode_bucket, self._commit_bucket, compress=compress_fn,
@@ -461,7 +467,8 @@ class GradSync:
                 # of the DensityController's feedback profile
                 extra[sparsify.DENSITYN_KEY.format(key=b.key)] = jnp.mean(
                     (out != 0).astype(jnp.float32))
-            bk.scatter_bucket(b, out, synced_flat)
+            with jax.named_scope(scopes.SYNC_ENCODE):
+                bk.scatter_bucket(b, out, synced_flat)
         synced = jax.tree_util.tree_unflatten(treedef, synced_flat)
         stats = bk.reduce_stats(self.plan, per_bucket, extra)
         if residual is None:

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.analysis import scopes
 from repro.data.pipeline import make_batch_specs
 from repro.models.common import ArchConfig, make_ctx
 from repro.models.model import (Model, assert_mesh_invariant_params,
@@ -81,6 +82,34 @@ class Program:
                                        gradsync=self.gradsync),
                      out_shardings=shardings)
         return fn(params)
+
+    def step_scopes(self) -> dict:
+        """``{HLO instruction name: layer}`` of the compiled train step
+        (``analysis/scopes.py``): the step is lowered with the program's
+        own abstract parameters, optimizer state and batch, in the
+        shardings and with the donation of the real call, so the
+        executable is the one the trainer runs (from the persistent
+        cache where it is on).  Maps a device trace of the trainer, whose
+        events are named by instruction, to fwd/bwd/remat/opt/sync."""
+        if self.train_step is None:
+            raise ValueError("call attach_train(prog, ...) first")
+        ctx = self.model.ctx
+
+        def placed(shapes, specs):
+            return jax.tree.map(
+                lambda s, p: jax.ShapeDtypeStruct(
+                    s.shape, s.dtype, sharding=NamedSharding(self.mesh, p)),
+                shapes, specs)
+
+        params = placed(self.param_shapes, self.param_specs)
+        opt = placed(
+            st.abstract_opt_state(self.tcfg, self.param_shapes, ctx,
+                                  self.param_specs, gradsync=self.gradsync),
+            st.opt_pspecs(self.tcfg, self.param_specs, ctx,
+                          gradsync=self.gradsync))
+        batch = placed(self.batch_specs["shapes"], self.batch_specs["pspecs"])
+        compiled = self.train_step.lower(params, opt, batch).compile()
+        return scopes.instruction_scopes(compiled.as_text())
 
 
 def build_program(cfg: ArchConfig, mesh: Mesh,

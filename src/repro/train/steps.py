@@ -31,6 +31,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.analysis import scopes
 from repro.core.zen import GradSync, SyncConfig
 from repro.models.common import ShardCtx
 from repro.models.model import Model
@@ -258,9 +259,13 @@ def make_train_step(model: Model, tcfg: TrainerConfig, param_specs,
         gradsync = make_gradsync(model, tcfg, param_specs, param_shapes,
                                  sparsity_profiles)
 
+    def loss_fn(params, batch):
+        with jax.named_scope(scopes.FWD):
+            return model.train_loss(params, batch)
+
     def step_fn(params, opt_state, batch):
         (loss, metrics), grads = jax.value_and_grad(
-            model.train_loss, has_aux=True)(params, batch)
+            loss_fn, has_aux=True)(params, batch)
 
         # --- 2. complete model-replicated grads over the model axis --------
         if ctx.tp > 1:
@@ -283,54 +288,57 @@ def make_train_step(model: Model, tcfg: TrainerConfig, param_specs,
             grads, sync_stats = gradsync(grads)
         metrics = {**metrics, **sync_stats}
 
-        # --- grad clip (global norm; sharded leaves psum over model) --------
-        if tcfg.opt.grad_clip > 0:
-            flat_g, _ = jax.tree.flatten(grads)
-            sq = jnp.float32(0)
-            for g, s in zip(flat_g, spec_leaves):
-                ss = jnp.sum(g.astype(jnp.float32) ** 2)
-                if ctx.tp > 1 and _has_model(s):
-                    ss = lax.psum(ss, ctx.tp_axis)
-                sq = sq + ss
-            gn = jnp.sqrt(sq)
-            scale = jnp.minimum(1.0, tcfg.opt.grad_clip / (gn + 1e-9))
-            grads = jax.tree.map(lambda g: g * scale.astype(g.dtype), grads)
-            metrics["grad_norm"] = gn
+        with jax.named_scope(scopes.OPT):
+            # --- grad clip (global norm; sharded leaves psum over model) ----
+            if tcfg.opt.grad_clip > 0:
+                flat_g, _ = jax.tree.flatten(grads)
+                sq = jnp.float32(0)
+                for g, s in zip(flat_g, spec_leaves):
+                    ss = jnp.sum(g.astype(jnp.float32) ** 2)
+                    if ctx.tp > 1 and _has_model(s):
+                        ss = lax.psum(ss, ctx.tp_axis)
+                    sq = sq + ss
+                gn = jnp.sqrt(sq)
+                scale = jnp.minimum(1.0, tcfg.opt.grad_clip / (gn + 1e-9))
+                grads = jax.tree.map(lambda g: g * scale.astype(g.dtype),
+                                     grads)
+                metrics["grad_norm"] = gn
 
-        # --- 4. parameter update --------------------------------------------
-        step = opt_state["step"]
-        if tcfg.zero1:
-            r = lax.axis_index(zaxes) if (world > 1) else 0
+            # --- 4. parameter update ----------------------------------------
+            step = opt_state["step"]
+            if tcfg.zero1:
+                r = lax.axis_index(zaxes) if (world > 1) else 0
 
-            def leaf_update(p, g, st):
-                c = opt_chunk_size(p.size, world)
-                gf = jnp.pad(g.reshape(-1).astype(jnp.float32),
-                             (0, world * c - p.size))
-                pf = jnp.pad(p.reshape(-1).astype(jnp.float32),
-                             (0, world * c - p.size))
-                g_my = lax.dynamic_slice(gf, (r * c,), (c,))
-                p_my = lax.dynamic_slice(pf, (r * c,), (c,))
-                # moments arrive as this rank's [1, c] shard of [world, c]
-                st_my = jax.tree.map(lambda m: m[0], st)
-                p_new, st_new = upd(tcfg.opt, p_my, g_my, st_my, step)
-                if world > 1:
-                    p_full = lax.all_gather(p_new, zaxes, tiled=True)
-                else:
-                    p_full = p_new
-                p_out = p_full[: p.size].reshape(p.shape).astype(p.dtype)
-                st_out = jax.tree.map(lambda m: m[None], st_new)
-                return p_out, st_out
+                def leaf_update(p, g, st):
+                    c = opt_chunk_size(p.size, world)
+                    gf = jnp.pad(g.reshape(-1).astype(jnp.float32),
+                                 (0, world * c - p.size))
+                    pf = jnp.pad(p.reshape(-1).astype(jnp.float32),
+                                 (0, world * c - p.size))
+                    g_my = lax.dynamic_slice(gf, (r * c,), (c,))
+                    p_my = lax.dynamic_slice(pf, (r * c,), (c,))
+                    # moments arrive as this rank's [1, c] shard of [world, c]
+                    st_my = jax.tree.map(lambda m: m[0], st)
+                    p_new, st_new = upd(tcfg.opt, p_my, g_my, st_my, step)
+                    if world > 1:
+                        with jax.named_scope(scopes.ZERO1_GATHER):
+                            p_full = lax.all_gather(p_new, zaxes, tiled=True)
+                    else:
+                        p_full = p_new
+                    p_out = p_full[: p.size].reshape(p.shape).astype(p.dtype)
+                    st_out = jax.tree.map(lambda m: m[None], st_new)
+                    return p_out, st_out
 
-            new_params, new_s = _zip_update(params, grads,
-                                            opt_state["leaves"], leaf_update)
-            new_state = {"leaves": new_s, "step": step + 1}
-        else:
-            def leaf_update_full(p, g, st):
-                return upd(tcfg.opt, p, g, st, step)
+                new_params, new_s = _zip_update(
+                    params, grads, opt_state["leaves"], leaf_update)
+                new_state = {"leaves": new_s, "step": step + 1}
+            else:
+                def leaf_update_full(p, g, st):
+                    return upd(tcfg.opt, p, g, st, step)
 
-            new_params, new_state_leaves = _zip_update(
-                params, grads, opt_state["leaves"], leaf_update_full)
-            new_state = {"leaves": new_state_leaves, "step": step + 1}
+                new_params, new_state_leaves = _zip_update(
+                    params, grads, opt_state["leaves"], leaf_update_full)
+                new_state = {"leaves": new_state_leaves, "step": step + 1}
 
         if "residual" in opt_state:
             # EF memory: per-device state, untouched by ZeRO chunking

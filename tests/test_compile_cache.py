@@ -31,3 +31,18 @@ def test_default_dir_is_fixed_and_ignored(monkeypatch, restore_cache_dir):
     assert jax.config.jax_compilation_cache_dir == first
     ignored = (REPO / ".gitignore").read_text().split()
     assert ".jax_cache/" in ignored, ".jax_cache/ is not gitignored"
+
+
+def test_compile_counters_count_executables():
+    import numpy as np
+
+    f = jax.jit(lambda x: x * 3 - 1)
+    x = jax.device_put(np.ones(37, np.float32))
+    before = compile_cache.compile_stats()
+    f(x).block_until_ready()
+    once = compile_cache.compile_stats()
+    assert once["executables"] == before["executables"] + 1
+    assert once["seconds"] > before["seconds"]
+    # the same shape again is the executable JAX already holds
+    f(x).block_until_ready()
+    assert compile_cache.compile_stats()["executables"] == once["executables"]

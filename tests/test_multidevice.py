@@ -57,8 +57,9 @@ PRELUDE = textwrap.dedent("""
         for _ in range(steps):
             params, opt, m = prog.train_step(params, opt, batch)
             losses.append(float(m["loss"]))
-        return losses, {k: float(v) for k, v in m.items()
-                        if k.startswith("sync/")}
+        return losses, {**{k: float(v) for k, v in m.items()
+                           if k.startswith("sync/")},
+                        "plan": prog.gradsync.plan.counts()}
 """)
 
 # --- cross-mesh parity (DESIGN.md §9) --------------------------------------
@@ -171,7 +172,7 @@ WORKER_SYNC = PRELUDE + textwrap.dedent("""
     assert all(np.isfinite(x) for x in comp), comp
     # step-0 loss is pre-update (same seed, same params): must match dense
     assert abs(comp[0] - dense[0]) < 1e-3, (comp[0], dense[0])
-    assert comp_m.get("sync/compressed_buckets", 0) > 0, comp_m
+    assert comp_m["plan"]["compressed_buckets"] > 0, comp_m
     comp_wire = comp_m["sync/sparse_sent_words"] + comp_m["sync/dense_words"]
     dense_wire = dense_m["sync/sparse_sent_words"] + dense_m["sync/dense_words"]
     assert comp_wire < 0.25 * dense_wire, (comp_wire, dense_wire)

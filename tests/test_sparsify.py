@@ -253,7 +253,8 @@ def test_noef_keeps_no_state():
     assert gs.init_residual() == {}
     synced, nres, stats = _vsync(gs, _tree_grads(_tree_shapes()), {})
     assert nres == {}
-    assert "sync/compressed_buckets" in stats
+    assert gs.plan.counts()["compressed_buckets"] > 0
+    assert "sync/compressed_buckets" not in stats
 
 
 def test_density_metrics_reported():

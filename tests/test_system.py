@@ -35,6 +35,7 @@ def _run(cfg, mesh, tcfg, steps, seq=32, batch=4, seed=0,
         losses.append(float(m["loss"]))
     if with_metrics:
         sync_m = {k: float(v) for k, v in m.items() if k.startswith("sync/")}
+        sync_m["plan"] = prog.gradsync.plan.counts()
         return losses, params, sync_m
     return losses, params
 
@@ -117,12 +118,12 @@ def test_auto_scheme_selection(mesh):
     # (an under-provisioned 0.05 budget drops rows for SOME hash seeds)
     t_lo = TrainerConfig(sync=SyncConfig(scheme="auto", density_budget=0.15))
     l1, _, m1 = _run(cfg, mesh, t_lo, steps=2, with_metrics=True)
-    assert m1.get("sync/buckets[zen]", 0) > 0, m1
+    assert m1["plan"].get("buckets[zen]", 0) > 0, m1
     assert m1["sync/overflow"] == 0, m1
     # absurd budget: auto must fall back to dense (zen would be larger)
     t_hi = TrainerConfig(sync=SyncConfig(scheme="auto", density_budget=5.0))
     l2, _, m2 = _run(cfg, mesh, t_hi, steps=2, with_metrics=True)
-    assert m2.get("sync/buckets[zen]", 0) == 0, m2
+    assert m2["plan"].get("buckets[zen]", 0) == 0, m2
     t_dense = TrainerConfig(sync=SyncConfig(scheme="dense"))
     l3, _ = _run(cfg, mesh, t_dense, steps=2)
     np.testing.assert_allclose(l1, l3, rtol=1e-3)  # zen exact (no overflow)
