@@ -1,9 +1,9 @@
-"""Optimizers operating on flat f32 chunks (ZeRO-1 friendly).
+"""Elementwise optimizers (ZeRO-1 friendly).
 
-The trainer flattens every param leaf, pads to a multiple of the
-data-parallel world, and hands each rank its chunk; these update rules are
-shape-agnostic so they work on full leaves (smoke tests) and chunks (ZeRO-1)
-alike.
+Under ZeRO-1 the trainer hands each rank a slice of every leaf's leading
+axis, in the leaf's own dtype, with f32 moments of the same shape; these
+update rules are shape-agnostic (f32 arithmetic, the parameter rounded to
+its own dtype once) so they work on full leaves and chunks alike.
 """
 from __future__ import annotations
 

@@ -14,8 +14,8 @@ Gradient flow inside one train step:
      XLA's latency-hiding scheduler can overlap bucket *i*'s collective
      with bucket *i+1*'s encode.  ``SyncConfig.bucket_bytes=None`` keeps
      the monolithic per-leaf path bit-exactly;
-  4. ZeRO-1 update: each (pod, data) rank updates its flat chunk of every
-     leaf and the new params are all-gathered back.
+  4. ZeRO-1 update: each (pod, data) rank updates its rows of every
+     leaf's leading axis and the new rows are all-gathered back.
 
 Serve steps (prefill / decode) use the sequence-sharded KV cache layout
 from ``repro.models`` (context-parallel decode over ``model``).
@@ -86,19 +86,26 @@ def _zero_world(ctx: ShardCtx) -> int:
 # ZeRO-1 optimizer state
 # ---------------------------------------------------------------------------
 
-def opt_chunk_size(local_size: int, world: int) -> int:
-    return -(-local_size // world)
+def opt_chunk_size(d0: int, world: int) -> int:
+    return -(-d0 // world)
 
 
-def _shard_divisor(spec: P, ctx: ShardCtx) -> int:
-    div = 1
-    sizes = ctx.axis_sizes
-    for ax in spec:
+def moment_shape(local_shape: tuple, world: int) -> tuple:
+    """Global ZeRO-1 moment shape for a per-device shard ``local_shape``
+    (a scalar counts as (1,)): dim 0 padded to a multiple of ``world``."""
+    d0, *rest = tuple(local_shape) or (1,)
+    return (world * opt_chunk_size(d0, world), *rest)
+
+
+def _local_shape(shape: tuple, spec: P, sizes: dict) -> tuple:
+    """A leaf's global shape -> its per-device shard under ``spec``."""
+    out = list(shape)
+    for i, ax in enumerate(spec):
         if ax is None:
             continue
         for a in (ax if isinstance(ax, tuple) else (ax,)):
-            div *= sizes.get(a, 1)
-    return div
+            out[i] //= sizes.get(a, 1)
+    return tuple(out)
 
 
 def _device_world(ctx: ShardCtx) -> int:
@@ -116,10 +123,10 @@ def residual_axes(ctx: ShardCtx) -> tuple:
 
 def init_opt_state(tcfg: TrainerConfig, params, ctx: ShardCtx, param_specs,
                    gradsync=None):
-    """Global optimizer state.  ZeRO-1: per-leaf moments shaped
-    [world, chunk] where chunk covers the LOCAL (per-device) param shard
-    (dim0 sharded over the zero axes).  When ``gradsync`` compresses with
-    error feedback, a ``residual`` entry carries one zero f32 vector per
+    """Global optimizer state.  ZeRO-1: per-leaf f32 moments in the shape
+    of the LOCAL (per-device) param shard, dim 0 chunked over the zero
+    axes (``moment_shape``).  When ``gradsync`` compresses with error
+    feedback, a ``residual`` entry carries one zero f32 vector per
     compressed bucket and device (DESIGN.md §8)."""
     world = _zero_world(ctx)
     init = INITS[tcfg.opt.kind]
@@ -127,9 +134,8 @@ def init_opt_state(tcfg: TrainerConfig, params, ctx: ShardCtx, param_specs,
     def leaf(p, spec):
         if not tcfg.zero1:
             return init(p)
-        local = p.size // _shard_divisor(spec, ctx)
-        c = opt_chunk_size(local, world)
-        return init(jnp.zeros((world, c), jnp.float32))
+        local = _local_shape(p.shape, spec, ctx.axis_sizes)
+        return init(jnp.zeros(moment_shape(local, world), jnp.float32))
 
     state = jax.tree.map(leaf, params, param_specs)
     out = {"leaves": state, "step": jnp.zeros((), jnp.int32)}
@@ -144,7 +150,7 @@ def opt_pspecs(tcfg: TrainerConfig, param_specs, ctx: ShardCtx,
     zaxes = zero_axes(ctx)
 
     def leaf(spec: P):
-        moment_spec = (P(zaxes, None) if tcfg.zero1 else spec)
+        moment_spec = P(zaxes) if tcfg.zero1 else spec
         return {k: moment_spec for k in INITS[tcfg.opt.kind](
             jnp.zeros((1,), jnp.float32))}
 
@@ -163,12 +169,9 @@ def abstract_opt_state(tcfg: TrainerConfig, param_shapes, ctx: ShardCtx,
     names = list(INITS[tcfg.opt.kind](jnp.zeros((1,), jnp.float32)))
 
     def leaf(p, spec):
-        if tcfg.zero1:
-            local = int(np.prod(p.shape)) // _shard_divisor(spec, ctx)
-            c = opt_chunk_size(local, world)
-            return {k: jax.ShapeDtypeStruct((world, c), jnp.float32)
-                    for k in names}
-        return {k: jax.ShapeDtypeStruct(p.shape, jnp.float32) for k in names}
+        shape = (moment_shape(_local_shape(p.shape, spec, ctx.axis_sizes),
+                              world) if tcfg.zero1 else p.shape)
+        return {k: jax.ShapeDtypeStruct(shape, jnp.float32) for k in names}
 
     out = {"leaves": jax.tree.map(leaf, param_shapes, param_specs),
            "step": jax.ShapeDtypeStruct((), jnp.int32)}
@@ -197,19 +200,9 @@ def _residual_struct(gradsync, ctx: ShardCtx):
 
 def local_param_shapes(param_shapes, param_specs, ctx: ShardCtx):
     """Global ShapeDtypeStructs -> per-device (shard_map-local) shapes."""
-    sizes = ctx.axis_sizes
-
     def leaf(sds, spec):
-        shape = list(sds.shape)
-        for i, ax in enumerate(spec):
-            if ax is None:
-                continue
-            axs = ax if isinstance(ax, tuple) else (ax,)
-            div = 1
-            for a in axs:
-                div *= sizes.get(a, 1)
-            shape[i] = shape[i] // div
-        return jax.ShapeDtypeStruct(tuple(shape), sds.dtype)
+        return jax.ShapeDtypeStruct(
+            _local_shape(sds.shape, spec, ctx.axis_sizes), sds.dtype)
 
     return jax.tree.map(leaf, param_shapes, param_specs,
                         is_leaf=lambda x: isinstance(x, P))
@@ -250,7 +243,6 @@ def make_train_step(model: Model, tcfg: TrainerConfig, param_specs,
     ctx = model.ctx
     world = _zero_world(ctx)
     zaxes = zero_axes(ctx)
-    upd = UPDATES[tcfg.opt.kind]
 
     spec_leaves = jax.tree.leaves(
         param_specs, is_leaf=lambda x: isinstance(x, P))
@@ -306,39 +298,17 @@ def make_train_step(model: Model, tcfg: TrainerConfig, param_specs,
 
             # --- 4. parameter update ----------------------------------------
             step = opt_state["step"]
-            if tcfg.zero1:
-                r = lax.axis_index(zaxes) if (world > 1) else 0
+            r = lax.axis_index(zaxes) if (world > 1) else 0
 
-                def leaf_update(p, g, st):
-                    c = opt_chunk_size(p.size, world)
-                    gf = jnp.pad(g.reshape(-1).astype(jnp.float32),
-                                 (0, world * c - p.size))
-                    pf = jnp.pad(p.reshape(-1).astype(jnp.float32),
-                                 (0, world * c - p.size))
-                    g_my = lax.dynamic_slice(gf, (r * c,), (c,))
-                    p_my = lax.dynamic_slice(pf, (r * c,), (c,))
-                    # moments arrive as this rank's [1, c] shard of [world, c]
-                    st_my = jax.tree.map(lambda m: m[0], st)
-                    p_new, st_new = upd(tcfg.opt, p_my, g_my, st_my, step)
-                    if world > 1:
-                        with jax.named_scope(scopes.ZERO1_GATHER):
-                            p_full = lax.all_gather(p_new, zaxes, tiled=True)
-                    else:
-                        p_full = p_new
-                    p_out = p_full[: p.size].reshape(p.shape).astype(p.dtype)
-                    st_out = jax.tree.map(lambda m: m[None], st_new)
-                    return p_out, st_out
+            def leaf_update(p, g, st):
+                if tcfg.zero1:
+                    return zero1_update(tcfg.opt, p, g, st, step, r, world,
+                                        zaxes)
+                return UPDATES[tcfg.opt.kind](tcfg.opt, p, g, st, step)
 
-                new_params, new_s = _zip_update(
-                    params, grads, opt_state["leaves"], leaf_update)
-                new_state = {"leaves": new_s, "step": step + 1}
-            else:
-                def leaf_update_full(p, g, st):
-                    return upd(tcfg.opt, p, g, st, step)
-
-                new_params, new_state_leaves = _zip_update(
-                    params, grads, opt_state["leaves"], leaf_update_full)
-                new_state = {"leaves": new_state_leaves, "step": step + 1}
+            new_params, new_s = _zip_update(
+                params, grads, opt_state["leaves"], leaf_update)
+            new_state = {"leaves": new_s, "step": step + 1}
 
         if "residual" in opt_state:
             # EF memory: per-device state, untouched by ZeRO chunking
@@ -351,6 +321,35 @@ def make_train_step(model: Model, tcfg: TrainerConfig, param_specs,
         return new_params, new_state, metrics
 
     return step_fn
+
+
+def zero1_update(opt: OptConfig, p, g, st, step, r, world: int, zaxes):
+    """ZeRO-1 update of one leaf: rank ``r`` owns rows [r*c0, (r+1)*c0) of
+    dim 0, ``st`` their moments; p and g keep their dtypes.  Zero rows pad
+    dim 0 where ``world`` does not divide it; their moments stay zero."""
+    shape = p.shape or (1,)
+    c0 = opt_chunk_size(shape[0], world)
+    pad = world * c0 - shape[0]
+
+    def mine(x):
+        x = x.reshape(shape)
+        if pad:
+            x = jnp.pad(x, [(0, pad)] + [(0, 0)] * (len(shape) - 1))
+        return lax.dynamic_slice_in_dim(x, r * c0, c0, 0)
+
+    p_new, st_new = UPDATES[opt.kind](opt, mine(p), mine(g), st, step)
+    if world > 1:
+        with jax.named_scope(scopes.ZERO1_GATHER):
+            p_new = lax.all_gather(p_new, zaxes, axis=0, tiled=True)
+        # XLA:TPU copies a gathered result into the donated parameter; as
+        # a bare `copy` it also copies the parameter on entry (1.2 GB a step,
+        # qwen2-0.5b at dp=4 on a v5e), through this exact fusion it does not.
+        p_new = lax.reduce_precision(
+            p_new.astype(jnp.float32), exponent_bits=8,
+            mantissa_bits=jnp.finfo(p.dtype).nmant).astype(p.dtype)
+    if pad:
+        p_new = p_new[:shape[0]]
+    return p_new.reshape(p.shape), st_new
 
 
 def _zip_update(params, grads, states, fn):

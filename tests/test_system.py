@@ -1,5 +1,9 @@
 """End-to-end behaviour tests for the training system."""
 import dataclasses
+import os
+import subprocess
+import sys
+import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -48,19 +52,74 @@ def test_loss_decreases(mesh):
     assert all(np.isfinite(losses))
 
 
-def test_zero1_equals_full_optimizer(mesh):
-    """ZeRO-1 chunked update must be bit-compatible with the plain update
-    (single device: chunking is pure reshaping)."""
+def _zero1_pair(mesh, dtype: str) -> dict:
+    """Losses and final params (as f32, which holds bf16 exactly) of 3
+    steps with and without ZeRO-1 on ``mesh``."""
     cfg = dataclasses.replace(get_config("qwen2-0.5b").reduced(),
-                              dtype=jnp.float32)
-    t_zero = TrainerConfig(opt=OptConfig(lr=1e-3), zero1=True)
-    t_full = TrainerConfig(opt=OptConfig(lr=1e-3), zero1=False)
-    l1, p1 = _run(cfg, mesh, t_zero, steps=3)
-    l2, p2 = _run(cfg, mesh, t_full, steps=3)
-    np.testing.assert_allclose(l1, l2, rtol=1e-5)
-    for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32), atol=1e-5)
+                              dtype=jnp.dtype(dtype))
+    out = {}
+    for zero1 in (True, False):
+        tcfg = TrainerConfig(opt=OptConfig(lr=1e-3), zero1=zero1)
+        losses, params = _run(cfg, mesh, tcfg, steps=3)
+        out[f"{zero1}/loss"] = np.asarray(losses)
+        for i, leaf in enumerate(jax.tree.leaves(params)):
+            out[f"{zero1}/p{i}"] = np.asarray(leaf, np.float32)
+    return out
+
+
+# Without --xla_allow_excess_precision=false, XLA:CPU may keep a bf16
+# intermediate of the gradient path in f32 in one program and round it in
+# the other, as its fusions differ: a few elements then move by one bf16
+# ulp at dp=4.  With it, each program rounds where its source says, and
+# the comparison is exact.
+ZERO1_DP4_WORKER = textwrap.dedent("""
+    import os, sys
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4"
+                               " --xla_allow_excess_precision=false")
+    import numpy as np
+    sys.path.insert(0, {tests!r})
+    from test_system import _zero1_pair
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 1), ("data", "model"))
+    for dtype in ("float32", "bfloat16"):
+        np.savez(os.path.join({out!r}, dtype + ".npz"),
+                 **_zero1_pair(mesh, dtype))
+""")
+
+
+@pytest.fixture(scope="module")
+def zero1_dp4(tmp_path_factory):
+    """``_zero1_pair`` at dp=4 on virtual CPU devices, both dtypes, in one
+    subprocess (this process keeps its single device)."""
+    out = tmp_path_factory.mktemp("zero1_dp4")
+    env = dict(os.environ, PYTHONPATH=os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
+    script = ZERO1_DP4_WORKER.format(
+        tests=os.path.dirname(os.path.abspath(__file__)), out=str(out))
+    r = subprocess.run([sys.executable, "-c", script], env=env,
+                       capture_output=True, text=True, timeout=900)
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-4000:]
+    return {d: dict(np.load(out / f"{d}.npz"))
+            for d in ("float32", "bfloat16")}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("dp", [1, 4])
+def test_zero1_equals_full_optimizer(mesh, request, dp, dtype):
+    """ZeRO-1's chunked update is the plain update, element for element:
+    each rank runs the same AdamW arithmetic on its rows of every leaf,
+    so parameters (bf16 ones rounded once) and losses agree bit for bit
+    with ``zero1=False`` at any data-parallel degree."""
+    if dp == 1:
+        runs = _zero1_pair(mesh, dtype)
+    else:
+        runs = request.getfixturevalue("zero1_dp4")[dtype]
+    np.testing.assert_array_equal(runs["True/loss"], runs["False/loss"])
+    n = sum(k.startswith("True/p") for k in runs)
+    assert n > 0
+    for i in range(n):
+        np.testing.assert_array_equal(runs[f"True/p{i}"],
+                                      runs[f"False/p{i}"], err_msg=f"p{i}")
 
 
 def test_all_sync_schemes_end_to_end(mesh):
