@@ -1,0 +1,9 @@
+"""Expert layer: device self time per step of the block ``moe.experts`` (the
+held experts' grouped matmuls of every expert layer), in the forward, the
+recomputed forward and the backward together, averaged over the chips
+(``blocks.py``)."""
+from benchmarks.chip import blocks
+
+
+def reduce(run):
+    return blocks.block_ms(run, "moe.experts")

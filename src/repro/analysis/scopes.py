@@ -25,6 +25,15 @@ Derived layers, named by JAX's transformations:
                  inside the backward by ``jax.checkpoint``
   unscoped       everything else, and instructions with no ``op_name``
 
+A second map, orthogonal to the layers, names the model's blocks whose
+time a cell reads (``instruction_blocks``): the innermost block scope of
+an instruction, in whichever layer it runs (fwd, remat or, through
+``transpose(...)``, bwd), else ``other``:
+
+  moe.route      the router, top-k, sort, dispatch gather, combine and
+                 balance loss of an expert layer (``models/moe.py``)
+  moe.experts    the held experts' grouped matmuls
+
 This module imports nothing from the trainer, so ``core/`` can use it.
 """
 from __future__ import annotations
@@ -42,7 +51,12 @@ SYNC_EXCHANGE = "sync.exchange"
 ZERO1_GATHER = "zero1.gather"
 UNSCOPED = "unscoped"
 
+MOE_ROUTE = "moe.route"
+MOE_EXPERTS = "moe.experts"
+OTHER = "other"
+
 NAMED = (FWD, SYNC_ENCODE, SYNC_EXCHANGE, OPT, ZERO1_GATHER)
+BLOCKS = (MOE_ROUTE, MOE_EXPERTS)
 ALL = (FWD, BWD, REMAT, OPT, SYNC_ENCODE, SYNC_EXCHANGE, ZERO1_GATHER, UNSCOPED)
 REMAT_MARK = "rematted_computation"
 # a scope seen through a transformation: ``jvp(fwd)`` is ``fwd``
@@ -68,6 +82,15 @@ def classify(op_name: str) -> str:
     return UNSCOPED
 
 
+def block_of(op_name: str) -> str:
+    """The block of one ``op_name``: its innermost block scope, seen
+    through any transformation, else ``other``."""
+    for p in map(_bare, reversed(op_name.split("/"))):
+        if p in BLOCKS:
+            return p
+    return OTHER
+
+
 def instruction_scopes(hlo_text: str) -> dict:
     """``{instruction name: layer}`` for every instruction of a compiled
     HLO module (``compiled.as_text()``).  An instruction that XLA made and
@@ -75,6 +98,17 @@ def instruction_scopes(hlo_text: str) -> dict:
     inside its fused computation (the one nearest its root), and otherwise
     the layer of the instruction that calls its computation (a loop body
     takes its ``while``'s); in the entry computation it is unscoped."""
+    return _instruction_map(hlo_text, classify, UNSCOPED)
+
+
+def instruction_blocks(hlo_text: str) -> dict:
+    """``{instruction name: block}`` for every instruction of a compiled
+    HLO module, by the rules of ``instruction_scopes`` with ``block_of``
+    in place of ``classify``; ``other`` where no block scope is found."""
+    return _instruction_map(hlo_text, block_of, OTHER)
+
+
+def _instruction_map(hlo_text: str, name_of, default: str) -> dict:
     module = HloModule.parse(hlo_text)
     caller = {c: op for comp in module.computations.values()
               for op in comp.ops for c in op.called}
@@ -84,14 +118,14 @@ def instruction_scopes(hlo_text: str) -> dict:
     def layer(op) -> str:
         if op.name in memo:
             return memo[op.name]
-        memo[op.name] = UNSCOPED          # a cycle cannot recurse forever
+        memo[op.name] = default           # a cycle cannot recurse forever
         name = op.op_name
         if not name and op.kind == "fusion" and op.called:
             fused = module.computations.get(op.called[0])
             inner = [o.op_name for o in (fused.ops if fused else ()) if o.op_name]
             name = inner[-1] if inner else ""
         if name:
-            memo[op.name] = classify(name)
+            memo[op.name] = name_of(name)
         elif home[op.name] in caller:
             memo[op.name] = layer(caller[home[op.name]])
         return memo[op.name]

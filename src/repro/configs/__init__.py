@@ -2,14 +2,18 @@
 from repro.configs import (
     olmoe_1b_7b, whisper_medium, qwen2_0_5b, phi3_5_moe, phi4_mini,
     mamba2_370m, zamba2_1_2b, pixtral_12b, qwen2_5_3b, minicpm3_4b,
+    deepseek_v2_lite,
 )
 
 _MODULES = [
     olmoe_1b_7b, whisper_medium, qwen2_0_5b, phi3_5_moe, phi4_mini,
     mamba2_370m, zamba2_1_2b, pixtral_12b, qwen2_5_3b, minicpm3_4b,
+    deepseek_v2_lite,
 ]
 
-CONFIGS = {m.CONFIG.name: m.CONFIG for m in _MODULES}
+# published sizes, then one chip's share of a stated deployment
+CONFIGS = {c.name: c for c in [m.CONFIG for m in _MODULES]
+           + [deepseek_v2_lite.EP8_SHARE]}
 ALL_ARCHS = list(CONFIGS)
 
 # input shapes assigned to this paper

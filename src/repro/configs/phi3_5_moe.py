@@ -1,5 +1,11 @@
 """Phi-3.5-MoE (42B total / 6.6B active): 16-expert top-2
-[hf:microsoft/Phi-3.5-MoE-instruct]."""
+[hf:microsoft/Phi-3.5-MoE-instruct].
+
+How the program runs it (models/moe.py, DESIGN.md §16): the router's
+logits, softmax and top-k in float32; every pair routed to a held expert
+computed (dropless; ``capacity_factor`` bounds only ``moe_a2a``'s
+exchange, so ``moe/dropped`` is reported there alone); the Switch-style
+balance loss over the whole batch, averaged over layers, weight 0.01."""
 from repro.models.common import ArchConfig
 
 CONFIG = ArchConfig(

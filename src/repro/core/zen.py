@@ -375,7 +375,6 @@ class GradSync:
             g = enc[0]
             zen_enc = enc[1] if len(enc) > 1 else None
             out, st = self._run_stage(bucket, 0, g, enc=zen_enc)
-            out = out / n  # mean-reduce convention (all schemes SUM)
         else:
             g_mid, st0 = enc
             out, st1 = self._run_stage(bucket, 1, g_mid)
@@ -383,7 +382,8 @@ class GradSync:
                 sent_words=st0.sent_words + st1.sent_words,
                 overflow=st0.overflow + st1.overflow,
                 by_level=(st0.sent_words, st1.sent_words))
-            out = out / n
+        if n > 1:
+            out = out / n  # mean-reduce convention (all schemes SUM)
         if self.pod_axis is not None:
             out = lax.pmean(out, self.pod_axis)
         return out, st

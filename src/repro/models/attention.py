@@ -186,7 +186,8 @@ def gqa_make_cross_cache(p, name, enc_out, cfg: ArchConfig, ctx: ShardCtx):
 
 
 # ---------------------------------------------------------------------------
-# MLA (Multi-head Latent Attention — minicpm3)
+# MLA (Multi-head Latent Attention — minicpm3 with q compression,
+# deepseek-v2 without)
 # ---------------------------------------------------------------------------
 
 def init_mla(b: ParamBuilder, name: str, cfg: ArchConfig, ctx: ShardCtx):
@@ -196,17 +197,29 @@ def init_mla(b: ParamBuilder, name: str, cfg: ArchConfig, ctx: ShardCtx):
     qr, kvr = cfg.mla_q_rank, cfg.mla_kv_rank
     up_mode = "col" if ctx.shard_heads else "rep"
     o_mode = "row" if ctx.shard_heads else "rep"
-    L.init_linear(sub, "q_down", d, qr, mode="rep", tp=ctx.tp)
-    L.init_linear(sub, "q_up", qr, H * (hd_n + rd), mode=up_mode, tp=ctx.tp)
+    if qr:
+        L.init_linear(sub, "q_down", d, qr, mode="rep", tp=ctx.tp)
+        L.init_linear(sub, "q_up", qr, H * (hd_n + rd), mode=up_mode, tp=ctx.tp)
+        L.init_rmsnorm(sub, "q_norm", qr)
+    else:
+        L.init_linear(sub, "q", d, H * (hd_n + rd), mode=up_mode, tp=ctx.tp)
     L.init_linear(sub, "kv_down", d, kvr + rd, mode="rep", tp=ctx.tp)
     L.init_linear(sub, "kv_up", kvr, H * (hd_n + vd), mode=up_mode, tp=ctx.tp)
     L.init_linear(sub, "o", H * vd, d, mode=o_mode, tp=ctx.tp)
-    L.init_rmsnorm(sub, "q_norm", qr)
     L.init_rmsnorm(sub, "kv_norm", kvr)
     if ctx.h_pad:
-        _zero_pad_cols(sub, "q_up", cfg.n_heads * (hd_n + rd))
+        _zero_pad_cols(sub, "q_up" if qr else "q", cfg.n_heads * (hd_n + rd))
         _zero_pad_cols(sub, "kv_up", cfg.n_heads * (hd_n + vd))
         _zero_pad_rows(sub, "o", cfg.n_heads * vd)
+
+
+def mla_scale(cfg: ArchConfig) -> float:
+    """Softmax scale: 1/sqrt(q dim), times YaRN's mscale squared."""
+    scale = 1.0 / math.sqrt(cfg.hd + cfg.mla_rope_dim)
+    y = cfg.yarn
+    if y is not None and y.mscale_all_dim:
+        scale *= L.yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
 
 
 def _mla_qkv(sub, x, cfg: ArchConfig, ctx: ShardCtx, positions):
@@ -215,14 +228,20 @@ def _mla_qkv(sub, x, cfg: ArchConfig, ctx: ShardCtx, positions):
     B, S, _ = x.shape
     Hl = _heads_local(cfg, ctx)
     hd_n, rd = cfg.hd, cfg.mla_rope_dim
-    cq = L.rmsnorm(sub["q_norm"], L.linear_rep(sub, "q_down", x))
-    q = L.linear_col(sub, "q_up", cq).reshape(B, S, Hl, hd_n + rd)
+    eps = cfg.norm_eps
+    if cfg.mla_q_rank:
+        cq = L.rmsnorm(sub["q_norm"], L.linear_rep(sub, "q_down", x), eps)
+        q = L.linear_col(sub, "q_up", cq)
+    else:
+        q = L.linear_col(sub, "q", x)
+    q = q.reshape(B, S, Hl, hd_n + rd)
     kv_c = L.linear_rep(sub, "kv_down", x)
-    c = L.rmsnorm(sub["kv_norm"], kv_c[..., :cfg.mla_kv_rank])
+    c = L.rmsnorm(sub["kv_norm"], kv_c[..., :cfg.mla_kv_rank], eps)
     k_rope = kv_c[..., cfg.mla_kv_rank:]
     q_nope, q_rope = q[..., :hd_n], q[..., hd_n:]
-    q_rope = L.rope(q_rope, positions, cfg.rope_theta)
-    k_rope = L.rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    q_rope = L.rope(q_rope, positions, cfg.rope_theta, yarn=cfg.yarn)
+    k_rope = L.rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                    yarn=cfg.yarn)[:, :, 0]
     return jnp.concatenate([q_nope, q_rope], -1), c, k_rope
 
 
@@ -239,7 +258,8 @@ def mla_train(p, name, x, cfg: ArchConfig, ctx: ShardCtx, *,
         [kv[..., :hd_n], jnp.broadcast_to(k_rope[:, :, None, :],
                                           (B, S, Hl, rd))], -1)
     v = kv[..., hd_n:]
-    out = L.flash_attention(q, k, v, causal=True, window=window)
+    out = L.flash_attention(q, k, v, causal=True, window=window,
+                            scale=mla_scale(cfg))
     out = out.reshape(B, S, Hl * vd)
     return (L.linear_row(sub, "o", out, ctx) if ctx.shard_heads
             else L.linear_rep(sub, "o", out))
@@ -279,7 +299,7 @@ def mla_decode(p, name, x, cache, t, cfg: ArchConfig, ctx: ShardCtx, *,
         cache["c"][:, :, None, :], cache["kr"][:, :, None, :], cache["pos"],
         c_new[:, None, :], kr_new[:, None, :], t, ctx)
     cc, krc = cc[:, :, 0, :], krc[:, :, 0, :]
-    scale = 1.0 / math.sqrt(hd_n + rd)
+    scale = mla_scale(cfg)
     s = (jnp.einsum("bhk,bsk->bhs", q_lat, cc.astype(jnp.float32))
          + jnp.einsum("bhr,bsr->bhs", q_rope.astype(jnp.float32),
                       krc.astype(jnp.float32))) * scale

@@ -45,6 +45,22 @@ def pad_to(x: int, m: int) -> int:
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling (arXiv:2309.00071), DeepSeek-V2's form: rope dims
+    whose wavelength fits ``beta_fast`` turns in ``original`` positions keep
+    their frequency, those with under ``beta_slow`` turns are divided by
+    ``factor``, a linear ramp lies between; the softmax scale gains
+    ``(0.1 * mscale_all_dim * ln(factor) + 1) ** 2``."""
+
+    factor: float
+    original: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchConfig:
     """One assigned architecture (exact sizes from the assignment block)."""
 
@@ -60,9 +76,18 @@ class ArchConfig:
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0             # the router's width: experts in the model
     top_k: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # moe_a2a's static exchange buffers only
+    d_ff_expert: int = 0           # expert (and shared-expert) width; 0 -> d_ff
+    n_shared_experts: int = 0      # one SwiGLU of n_shared x d_ff_expert, every token
+    first_k_dense: int = 0         # leading dense SwiGLU layers (d_ff) before the MoE stack
+    experts_held: int = 0          # experts [0, held) live here; 0 -> all n_experts
+    norm_topk: bool = True         # renormalise the top-k gates to sum to 1
+    aux_alpha: float = 0.01        # weight of the balance loss
+    seq_aux: bool = False          # balance loss per sequence, summed over MoE
+    #                                layers (DeepSeek-V2); else Switch-style
+    #                                over the batch, averaged over layers
     # --- SSM (mamba2 / hybrid) ---
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -71,11 +96,12 @@ class ArchConfig:
     ssm_chunk: int = 64
     # --- hybrid (zamba2-style shared attention block) ---
     shared_attn_every: int = 0     # apply shared attn block every k ssm layers
-    # --- MLA (minicpm3) ---
-    mla_q_rank: int = 0            # 0 -> standard GQA
-    mla_kv_rank: int = 0
+    # --- MLA (minicpm3, deepseek-v2); head_dim is the no-rope q/k dim ---
+    mla_q_rank: int = 0            # 0 -> plain q projection (no q compression)
+    mla_kv_rank: int = 0           # 0 -> standard GQA
     mla_rope_dim: int = 32
     mla_v_dim: int = 64
+    yarn: Optional[Yarn] = None    # rope scaling of the MLA rope dims
     # --- enc-dec (whisper backbone) ---
     n_enc_layers: int = 0
     enc_len: int = 1500            # encoder frames (stub embeddings)
@@ -84,6 +110,7 @@ class ArchConfig:
     # --- long-context ---
     sliding_window: int = 4096     # used by long_500k decode for attn archs
     # --- numerics ---
+    norm_eps: float = 1e-5         # RMSNorm epsilon of the decoder blocks
     dtype: Any = jnp.bfloat16
     source: str = ""               # citation
 
@@ -94,6 +121,20 @@ class ArchConfig:
     @property
     def is_attn_free(self) -> bool:
         return self.kind == "ssm"
+
+    @property
+    def is_mla(self) -> bool:
+        return self.mla_kv_rank > 0
+
+    @property
+    def expert_ff(self) -> int:
+        return self.d_ff_expert or self.d_ff
+
+    @property
+    def held(self) -> int:
+        """Routed experts whose weights live here (the router still scores
+        all ``n_experts``)."""
+        return self.experts_held or self.n_experts
 
     @property
     def d_inner(self) -> int:
@@ -120,19 +161,16 @@ class ArchConfig:
             per = (d * (2 * din + 2 * self.ssm_heads + 2 * self.ssm_state)
                    + din * d + din * self.ssm_conv)
             return emb + L * per
-        attn = d * (self.n_heads * self.hd) * 2 + d * (self.n_kv * self.hd) * 2
-        if self.mla_q_rank:
-            attn = (d * self.mla_q_rank
-                    + self.mla_q_rank * self.n_heads * (self.hd + self.mla_rope_dim)
-                    + d * (self.mla_kv_rank + self.mla_rope_dim)
-                    + self.mla_kv_rank * self.n_heads * (self.hd + self.mla_v_dim)
-                    + self.n_heads * self.mla_v_dim * d)
+        attn = self._attn_params()
         if self.kind == "moe":
-            ffn = self.n_experts * 3 * d * self.d_ff + d * self.n_experts
+            ffn = (self.held + self.n_shared_experts) * 3 * d * self.expert_ff \
+                + d * self.n_experts
         else:
             ffn = 3 * d * self.d_ff
         per = attn + ffn
         total = emb + L * per
+        if self.kind == "moe" and self.first_k_dense:
+            total += self.first_k_dense * (3 * d * self.d_ff - ffn)
         if self.kind == "enc_dec":
             total += self.n_enc_layers * (attn + ffn) + L * attn  # cross-attn
         if self.kind == "hybrid":
@@ -142,14 +180,26 @@ class ArchConfig:
             total = emb + L * ssm_per + (attn + ffn)  # one shared block
         return total
 
+    def _attn_params(self) -> int:
+        d, H = self.d_model, self.n_heads
+        if not self.is_mla:
+            return d * (H * self.hd) * 2 + d * (self.n_kv * self.hd) * 2
+        qk = H * (self.hd + self.mla_rope_dim)
+        q = (d * self.mla_q_rank + self.mla_q_rank * qk if self.mla_q_rank
+             else d * qk)
+        return (q + d * (self.mla_kv_rank + self.mla_rope_dim)
+                + self.mla_kv_rank * H * (self.hd + self.mla_v_dim)
+                + H * self.mla_v_dim * d)
+
     def n_active_params(self) -> int:
         """Active params per token (MoE uses top_k of n_experts)."""
         if self.kind != "moe":
             return self.n_params()
         d, L = self.d_model, self.n_layers
-        attn = d * (self.n_heads * self.hd) * 2 + d * (self.n_kv * self.hd) * 2
-        ffn = self.top_k * 3 * d * self.d_ff + d * self.n_experts
-        return self.vocab * d + L * (attn + ffn)
+        ffn = ((self.top_k + self.n_shared_experts) * 3 * d * self.expert_ff
+               + d * self.n_experts)
+        return (self.vocab * d + L * (self._attn_params() + ffn)
+                + self.first_k_dense * (3 * d * self.d_ff - ffn))
 
     def reduced(self) -> "ArchConfig":
         """Smoke-test variant: 2 layers, d_model<=256, <=4 experts."""
@@ -165,6 +215,9 @@ class ArchConfig:
             vocab=512,
             n_experts=min(self.n_experts, 4),
             top_k=min(self.top_k, 2),
+            d_ff_expert=min(self.d_ff_expert, 128),
+            experts_held=min(self.experts_held, 4),
+            first_k_dense=min(self.first_k_dense, 1),
             mla_q_rank=min(self.mla_q_rank, 64),
             mla_kv_rank=min(self.mla_kv_rank, 32),
             enc_len=min(self.enc_len, 24),
@@ -307,15 +360,19 @@ def validate_tp(cfg: ArchConfig, tp: int, *, shard_heads: bool,
              f"multiple of {VOCAB_PAD}, mesh-invariant) is not divisible "
              f"by tp={tp}; pick a tp dividing {vp}")
     uses_mlp = (cfg.kind in ("dense", "enc_dec", "vlm", "hybrid")
-                or bool(cfg.mla_q_rank))
+                or bool(cfg.mla_q_rank) or bool(cfg.first_k_dense))
     if uses_mlp:
         _require(cfg.d_ff % tp == 0, cfg,
                  f"d_ff={cfg.d_ff} is not divisible by tp={tp} "
                  f"(MLP is column->row parallel over the model axis)")
     if cfg.kind == "moe":
-        _require(cfg.n_experts % tp == 0, cfg,
-                 f"n_experts={cfg.n_experts} is not divisible by tp={tp} "
-                 f"(experts are sharded over the model axis)")
+        _require(cfg.held % tp == 0, cfg,
+                 f"the {cfg.held} experts held (n_experts={cfg.n_experts}) "
+                 f"are not divisible by tp={tp} (experts are sharded over "
+                 f"the model axis)")
+        shared = cfg.n_shared_experts * cfg.expert_ff
+        _require(shared % tp == 0, cfg,
+                 f"shared-expert width {shared} is not divisible by tp={tp}")
     if cfg.kind in ("ssm", "hybrid"):
         _require(cfg.d_inner % tp == 0, cfg,
                  f"d_inner={cfg.d_inner} is not divisible by tp={tp}")
@@ -324,7 +381,7 @@ def validate_tp(cfg: ArchConfig, tp: int, *, shard_heads: bool,
     # GQA head/KV nesting (MLA broadcasts k_rope per-head instead of
     # slicing replicated KV heads, so the nesting constraint is GQA-only)
     if (shard_heads and cfg.n_heads and not cfg.is_attn_free
-            and not cfg.mla_q_rank):
+            and not cfg.is_mla):
         H = h_pad or cfg.n_heads
         _require(H % cfg.n_kv == 0, cfg,
                  f"n_heads={H} is not a multiple of n_kv={cfg.n_kv}")
@@ -344,6 +401,10 @@ def make_ctx(cfg: ArchConfig, tp: int, dp: int, pods: int = 1,
         h_pad = pad_to(cfg.n_heads, tp)
         shard = True
     validate_tp(cfg, tp, shard_heads=shard, h_pad=h_pad)
+    if moe_a2a and tp > 1:
+        _require(cfg.held == cfg.n_experts, cfg,
+                 "moe_a2a exchanges tokens with the owners of all "
+                 "n_experts; it cannot run with a share of them held")
     if node_size > 1:
         _require(dp % node_size == 0, cfg,
                  f"node_size={node_size} does not divide the data-parallel "

@@ -10,6 +10,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
@@ -199,13 +200,42 @@ def lm_head_loss_chunked(p, name, x, labels, ctx: ShardCtx, *, mask=None,
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope(x, positions, theta: float):
-    """x [..., S, H, hd] (hd even), positions [..., S] int32."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_freqs(dim: int, theta: float, yarn) -> tuple:
+    """(inverse frequencies [dim/2], cos/sin gain) of YaRN rope scaling,
+    DeepSeek-V2's form (``common.Yarn``)."""
+    half = dim // 2
+    base = theta ** (-np.arange(half, dtype=np.float64) / half)
+
+    def turns_dim(turns):
+        return (dim * math.log(yarn.original / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(turns_dim(yarn.beta_fast)), 0)
+    hi = min(math.ceil(turns_dim(yarn.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(half) - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    freqs = base / yarn.factor * ramp + base * (1.0 - ramp)
+    gain = (yarn_mscale(yarn.factor, yarn.mscale)
+            / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+    return freqs.astype(np.float32), gain
+
+
+def rope(x, positions, theta: float, yarn=None):
+    """x [..., S, H, hd] (hd even), positions [..., S] int32; rotate-half
+    layout.  ``yarn`` (``common.Yarn``) rescales the frequencies."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs, gain = yarn_freqs(hd, theta, yarn)
     ang = positions[..., None].astype(jnp.float32) * freqs      # [..., S, half]
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    if yarn is not None and gain != 1.0:
+        cos, sin = cos * gain, sin * gain
     x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     return jnp.concatenate(
@@ -220,11 +250,13 @@ def rope(x, positions, theta: float):
 NEG = -1e30
 
 
-def _flash_inner(qf, kc, vc, pos_q, Sk, *, causal, window, chunk):
+def _flash_inner(qf, kc, vc, pos_q, Sk, *, causal, window, chunk,
+                 remat_chunks: bool = False):
     """Online-softmax over KV chunks for one q-block.
 
     qf: [B, Tq, KV, g, hd] (pre-scaled f32); kc/vc: [nC, B, chunk, KV, hd*];
-    pos_q: [Tq] (traced)."""
+    pos_q: [Tq] (traced).  ``remat_chunks`` recomputes each chunk's scores
+    in the backward instead of saving them."""
     B, Tq, KV, g, hd = qf.shape
     hd_v = vc.shape[-1]
 
@@ -256,12 +288,15 @@ def _flash_inner(qf, kc, vc, pos_q, Sk, *, causal, window, chunk):
     l0 = jnp.zeros((B, Tq, KV, g), jnp.float32)
     o0 = jnp.zeros((B, Tq, KV, g, hd_v), jnp.float32)
     nC = kc.shape[0]
+    if remat_chunks:
+        step = jax.checkpoint(step)
     (m, l, o), _ = lax.scan(step, (m0, l0, o0), (jnp.arange(nC), kc, vc))
     return o / jnp.maximum(l, 1e-30)[..., None]
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    chunk: int = 512, q_chunk: int = 1024, q_offset: int = 0):
+                    chunk: int = 512, q_chunk: int = 1024, q_offset: int = 0,
+                    scale: float | None = None):
     """Memory-efficient attention, tiled over BOTH q and kv blocks
     (lax.scan) — the f32 score transient is bounded by
     B * q_chunk * H * chunk * 4 bytes regardless of sequence length.
@@ -271,13 +306,15 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     ``window > 0`` restricts attention to the last ``window`` positions
     (sliding-window variant enabling long_500k on attention archs).
     Pure jnp — XLA fuses this well on TPU; the running-max/denominator
-    recurrence is the standard online-softmax.
+    recurrence is the standard online-softmax.  ``scale`` defaults to
+    1/sqrt(hd).
     """
     B, Sq, H, hd = q.shape
     hd_v = v.shape[-1]
     Sk, KV = k.shape[1], k.shape[2]
     g = H // KV
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     qf = (q.astype(jnp.float32) * scale).reshape(B, Sq, KV, g, hd)
 
     nC = (Sk + chunk - 1) // chunk
@@ -300,8 +337,10 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     def q_step(_, inp):
         qi, qblk = inp
         pos_q = qi * q_chunk + jnp.arange(q_chunk)
-        o = _flash_inner(qblk, kc, vc, pos_q, Sk,
-                         causal=causal, window=window, chunk=chunk)
+        # over several q-blocks the saved scores would grow as Sq * Sk:
+        # f32[B, Sq, H, Sk] twice, 8.6 GB at 4 rows of 4096 with 16 heads
+        o = _flash_inner(qblk, kc, vc, pos_q, Sk, causal=causal,
+                         window=window, chunk=chunk, remat_chunks=True)
         return None, o
 
     _, ob = lax.scan(q_step, None, (jnp.arange(nQ), qb))

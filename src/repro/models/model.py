@@ -37,15 +37,23 @@ from repro.models import attention as A
 from repro.models import moe as M
 from repro.models import ssm as S
 
-AUX_LOSS_W = 0.01
-
 
 # ---------------------------------------------------------------------------
 # small helpers
 # ---------------------------------------------------------------------------
 
-def _mean_tree(t):
-    return jax.tree.map(jnp.mean, t)
+def _reduce_layer_stats(t, cfg: ArchConfig):
+    """Per-layer stats over the layers: held pairs count the step's work
+    and add up, as the balance loss does where each layer adds its own
+    (``cfg.seq_aux``); the rest are averaged."""
+    summed = {"moe/held_pairs"} | ({"moe/aux_loss"} if cfg.seq_aux else set())
+    return {k: (jnp.sum if k in summed else jnp.mean)(v)
+            for k, v in t.items()}
+
+
+def _dense(cfg: ArchConfig) -> ArchConfig:
+    """The leading dense layers' view of a config (SwiGLU of d_ff)."""
+    return dataclasses.replace(cfg, kind="dense")
 
 
 def _zeros(shape, dtype, abstract: bool):
@@ -69,7 +77,7 @@ def _init_decoder_layer(b: ParamBuilder, cfg: ArchConfig, ctx: ShardCtx,
                         *, cross: bool = False):
     d = cfg.d_model
     L.init_rmsnorm(b, "ln1", d)
-    if cfg.mla_q_rank:
+    if cfg.is_mla:
         A.init_mla(b, "attn", cfg, ctx)
     else:
         A.init_gqa(b, "attn", cfg, ctx)
@@ -95,8 +103,9 @@ def _ffn(pl, x, cfg: ArchConfig, ctx: ShardCtx):
 
 def _decoder_layer_train(pl, x, cfg: ArchConfig, ctx: ShardCtx, *,
                          positions=None, window: int = 0, enc_out=None):
-    h = L.rmsnorm(pl["ln1"], x)
-    if cfg.mla_q_rank:
+    eps = cfg.norm_eps
+    h = L.rmsnorm(pl["ln1"], x, eps)
+    if cfg.is_mla:
         h = A.mla_train(pl, "attn", h, cfg, ctx, positions=positions,
                         window=window)
     else:
@@ -106,14 +115,15 @@ def _decoder_layer_train(pl, x, cfg: ArchConfig, ctx: ShardCtx, *,
     if enc_out is not None:
         x = x + A.gqa_train(pl, "xattn", L.rmsnorm(pl["lnx"], x), cfg, ctx,
                             kv_src=enc_out, use_rope=False)
-    y, stats = _ffn(pl, L.rmsnorm(pl["ln2"], x), cfg, ctx)
+    y, stats = _ffn(pl, L.rmsnorm(pl["ln2"], x, eps), cfg, ctx)
     return x + y, stats
 
 
 def _decoder_layer_decode(pl, x, cache, t, cfg: ArchConfig, ctx: ShardCtx, *,
                           window: int = 0, cross_cache=None):
-    h = L.rmsnorm(pl["ln1"], x[:, None])[:, 0]
-    if cfg.mla_q_rank:
+    eps = cfg.norm_eps
+    h = L.rmsnorm(pl["ln1"], x[:, None], eps)[:, 0]
+    if cfg.is_mla:
         h, c2 = A.mla_decode(pl, "attn", h, cache, t, cfg, ctx, window=window)
     else:
         h, c2 = A.gqa_decode(pl, "attn", h, cache, t, cfg, ctx, window=window)
@@ -122,7 +132,7 @@ def _decoder_layer_decode(pl, x, cache, t, cfg: ArchConfig, ctx: ShardCtx, *,
         x = x + A.gqa_cross_decode(
             pl, "xattn", L.rmsnorm(pl["lnx"], x[:, None])[:, 0],
             cross_cache, cfg, ctx)
-    y, _ = _ffn(pl, L.rmsnorm(pl["ln2"], x[:, None]), cfg, ctx)
+    y, _ = _ffn(pl, L.rmsnorm(pl["ln2"], x[:, None], eps), cfg, ctx)
     return x + y[:, 0], c2
 
 
@@ -155,12 +165,21 @@ class Model:
         elif cfg.kind == "enc_dec":
             self._build_enc_dec(b)
         else:
-            cross = False
-            b.stacked("layers", cfg.n_layers, functools.partial(
-                _init_decoder_layer, cfg=cfg, ctx=ctx, cross=cross))
+            for key, lcfg, n in self.stacks():
+                b.stacked(key, n, functools.partial(
+                    _init_decoder_layer, cfg=lcfg, ctx=ctx))
         if cfg.kind == "vlm":
             L.init_linear(b, "vis_proj", cfg.d_model, cfg.d_model,
                           mode="rep", tp=ctx.tp)
+
+    def stacks(self) -> list:
+        """(param key, layer config, depth) of each scanned decoder stack,
+        in order: the leading dense layers, if any, then ``layers``."""
+        cfg, k = self.cfg, self.cfg.first_k_dense
+        if not k:
+            return [("layers", cfg, cfg.n_layers)]
+        return [("dense_layers", _dense(cfg), k),
+                ("layers", cfg, cfg.n_layers - k)]
 
     def _init_ssm_layer(self, b: ParamBuilder):
         L.init_rmsnorm(b, "ln1", self.cfg.d_model)
@@ -231,14 +250,15 @@ class Model:
         if cfg.kind == "hybrid":
             return self._trunk_hybrid(params, x), {}
         # dense / moe / mla / enc-dec decoder / vlm
-        def body_stats(carry, pl):
-            y, stats = _decoder_layer_train(
-                pl, carry, cfg, ctx, positions=positions, window=window,
-                enc_out=enc_out)
-            return y, stats
+        stats = {}
+        for key, lcfg, _ in self.stacks():
+            def body_stats(carry, pl, lcfg=lcfg):
+                return _decoder_layer_train(
+                    pl, carry, lcfg, ctx, positions=positions, window=window,
+                    enc_out=enc_out)
 
-        x, stats = lax.scan(jax.checkpoint(body_stats), x, params["layers"])
-        return x, _mean_tree(stats) if stats else {}
+            x, stats = lax.scan(jax.checkpoint(body_stats), x, params[key])
+        return x, _reduce_layer_stats(stats, cfg) if stats else {}
 
     def _trunk_hybrid(self, params, x):
         cfg, ctx = self.cfg, self.ctx
@@ -300,7 +320,7 @@ class Model:
             enc_out = self._encode(params, batch["frames"])
         x, stats = self._trunk(params, x, positions=positions,
                                enc_out=enc_out)
-        x = L.rmsnorm(params["ln_f"], x)
+        x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
         if cfg.kind == "vlm":          # drop patch positions before the head
             x = x[:, batch["patches"].shape[1]:]
             labels = labels[:, batch["patches"].shape[1]:]
@@ -309,7 +329,7 @@ class Model:
         metrics = {"loss": loss, **{k: jnp.asarray(v) for k, v in
                                     (stats or {}).items()}}
         if cfg.kind == "moe" and "moe/aux_loss" in metrics:
-            loss = loss + AUX_LOSS_W * metrics["moe/aux_loss"]
+            loss = loss + cfg.aux_alpha * metrics["moe/aux_loss"]
         return loss, metrics
 
     # ---- serving ---------------------------------------------------------------
@@ -353,11 +373,12 @@ class Model:
                 lambda: A.gqa_make_cache(cfg, ctx, batch_local, cache_len,
                                          dtype=cfg.dtype),
                 ng, abstract)
-        elif cfg.mla_q_rank:
-            cache["layers"] = _stack_cache(
-                lambda: A.mla_make_cache(cfg, ctx, batch_local, cache_len,
-                                         dtype=cfg.dtype),
-                cfg.n_layers, abstract)
+        elif cfg.is_mla:
+            for key, _, n in self.stacks():
+                cache[key] = _stack_cache(
+                    lambda: A.mla_make_cache(cfg, ctx, batch_local, cache_len,
+                                             dtype=cfg.dtype),
+                    n, abstract)
         else:
             cache["layers"] = _stack_cache(mk_attn, cfg.n_layers, abstract)
         if cfg.kind == "enc_dec":
@@ -399,24 +420,23 @@ class Model:
                                                window=window)
         else:
             cross = cache.get("cross")
+            new_cache = dict(cache, t=t + 1)
+            for key, lcfg, _ in self.stacks():
+                def body(carry, inp, lcfg=lcfg):
+                    if cross is None:
+                        pl, cl = inp
+                        cc = None
+                    else:
+                        pl, cl, cx = inp
+                        cc = {"k": cx[0], "v": cx[1]}
+                    return _decoder_layer_decode(pl, carry, cl, t, lcfg, ctx,
+                                                 window=window, cross_cache=cc)
 
-            def body(carry, inp):
-                if cross is None:
-                    pl, cl = inp
-                    cc = None
-                else:
-                    pl, cl, cx = inp
-                    cc = {"k": cx[0], "v": cx[1]}
-                y, c2 = _decoder_layer_decode(pl, carry, cl, t, cfg, ctx,
-                                              window=window, cross_cache=cc)
-                return y, c2
+                xs = ((params[key], cache[key]) if cross is None
+                      else (params[key], cache[key], cross))
+                x, new_cache[key] = lax.scan(body, x, xs)
 
-            xs = ((params["layers"], cache["layers"]) if cross is None
-                  else (params["layers"], cache["layers"], cross))
-            x, new_layers = lax.scan(body, x, xs)
-            new_cache = dict(cache, t=t + 1, layers=new_layers)
-
-        x = L.rmsnorm(params["ln_f"], x)
+        x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
         logits_l = self._head_logits(params, x)            # [B, V/tp]
         # greedy global argmax over the vocab-sharded logits (padded
         # columns are already masked to NEG and cannot be selected)
@@ -541,38 +561,41 @@ class Model:
             h = carry
             y, _ = _decoder_layer_train(pl, h, cfg, ctx, positions=positions)
             kv = A.gqa_prefill_cache(
-                pl, "attn", L.rmsnorm(pl["ln1"], h), cfg, ctx) \
-                if not cfg.mla_q_rank else None
+                pl, "attn", L.rmsnorm(pl["ln1"], h), cfg, ctx)
             return y, kv
 
-        if cfg.mla_q_rank:
+        def body_mla(carry, pl, lcfg):
             # latent cache prefill for MLA
-            def body_mla(carry, pl):
-                h = carry
-                y, _ = _decoder_layer_train(pl, h, cfg, ctx)
-                xin = L.rmsnorm(pl["ln1"], h)
-                kv_c = L.linear_rep(pl["attn"], "kv_down", xin)
-                c = L.rmsnorm(pl["attn"]["kv_norm"],
-                              kv_c[..., :cfg.mla_kv_rank])
-                kr = L.rope(kv_c[:, :, None, cfg.mla_kv_rank:],
-                            jnp.arange(h.shape[1]), cfg.rope_theta)[:, :, 0]
-                tp = ctx.tp if ctx.decode_seq_shard else 1
-                r = ctx.tp_rank() if (ctx.tp > 1 and ctx.decode_seq_shard) else 0
-                sl = -(-Sfull // tp)
-                slots = jnp.arange(sl) * tp + r
-                safe = jnp.clip(slots, 0, Sfull - 1)
-                ok = (slots < Sfull)
-                return y, {
-                    "c": jnp.where(ok[None, :, None], c[:, safe], 0),
-                    "kr": jnp.where(ok[None, :, None], kr[:, safe], 0),
-                    "pos": jnp.where(ok, slots, -1).astype(jnp.int32),
-                }
-            x, layer_caches = lax.scan(body_mla, x, params["layers"])
+            h = carry
+            y, _ = _decoder_layer_train(pl, h, lcfg, ctx)
+            xin = L.rmsnorm(pl["ln1"], h, cfg.norm_eps)
+            kv_c = L.linear_rep(pl["attn"], "kv_down", xin)
+            c = L.rmsnorm(pl["attn"]["kv_norm"],
+                          kv_c[..., :cfg.mla_kv_rank], cfg.norm_eps)
+            kr = L.rope(kv_c[:, :, None, cfg.mla_kv_rank:],
+                        jnp.arange(h.shape[1]), cfg.rope_theta,
+                        yarn=cfg.yarn)[:, :, 0]
+            tp = ctx.tp if ctx.decode_seq_shard else 1
+            r = ctx.tp_rank() if (ctx.tp > 1 and ctx.decode_seq_shard) else 0
+            sl = -(-Sfull // tp)
+            slots = jnp.arange(sl) * tp + r
+            safe = jnp.clip(slots, 0, Sfull - 1)
+            ok = (slots < Sfull)
+            return y, {
+                "c": jnp.where(ok[None, :, None], c[:, safe], 0),
+                "kr": jnp.where(ok[None, :, None], kr[:, safe], 0),
+                "pos": jnp.where(ok, slots, -1).astype(jnp.int32),
+            }
+
+        cache = {"t": jnp.asarray(Sfull, jnp.int32)}
+        if cfg.is_mla:
+            for key, lcfg, _ in self.stacks():
+                x, cache[key] = lax.scan(
+                    functools.partial(body_mla, lcfg=lcfg), x, params[key])
         else:
-            x, layer_caches = lax.scan(body, x, params["layers"])
-        x_last = L.rmsnorm(params["ln_f"], x[:, -1])
+            x, cache["layers"] = lax.scan(body, x, params["layers"])
+        x_last = L.rmsnorm(params["ln_f"], x[:, -1], cfg.norm_eps)
         logits_l = self._head_logits(params, x_last)
-        cache = {"t": jnp.asarray(Sfull, jnp.int32), "layers": layer_caches}
         return logits_l, cache
 
 

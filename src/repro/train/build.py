@@ -44,6 +44,8 @@ class Program:
     # measured sparsity profiles used at the last (re)plan — the
     # DensityController feedback loop writes here via attach_train
     sparsity_profiles: Any = None
+    # (train_step, its compiled text): both step maps read one compile
+    _step_text: Any = dataclasses.field(default=None, repr=False)
 
     def init_params(self, seed: int = 0):
         shardings = jax.tree.map(
@@ -91,8 +93,22 @@ class Program:
         executable is the one the trainer runs (from the persistent
         cache where it is on).  Maps a device trace of the trainer, whose
         events are named by instruction, to fwd/bwd/remat/opt/sync."""
+        return scopes.instruction_scopes(self._compiled_step_text())
+
+    def step_blocks(self) -> dict:
+        """``{HLO instruction name: block}`` of the same executable:
+        ``moe.route``, ``moe.experts`` or ``other``, in whichever layer
+        the instruction runs (``analysis/scopes.instruction_blocks``)."""
+        return scopes.instruction_blocks(self._compiled_step_text())
+
+    def _compiled_step_text(self) -> str:
         if self.train_step is None:
             raise ValueError("call attach_train(prog, ...) first")
+        if self._step_text is None or self._step_text[0] is not self.train_step:
+            self._step_text = (self.train_step, self._compile_step_text())
+        return self._step_text[1]
+
+    def _compile_step_text(self) -> str:
         ctx = self.model.ctx
 
         def placed(shapes, specs):
@@ -108,8 +124,7 @@ class Program:
             st.opt_pspecs(self.tcfg, self.param_specs, ctx,
                           gradsync=self.gradsync))
         batch = placed(self.batch_specs["shapes"], self.batch_specs["pspecs"])
-        compiled = self.train_step.lower(params, opt, batch).compile()
-        return scopes.instruction_scopes(compiled.as_text())
+        return self.train_step.lower(params, opt, batch).compile().as_text()
 
 
 def build_program(cfg: ArchConfig, mesh: Mesh,

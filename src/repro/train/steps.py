@@ -282,6 +282,10 @@ def make_train_step(model: Model, tcfg: TrainerConfig, param_specs,
 
         with jax.named_scope(scopes.OPT):
             # --- grad clip (global norm; sharded leaves psum over model) ----
+            # The factor is applied in float32 inside each leaf's update (to
+            # the rank's chunk under ZeRO-1): rounded to bf16 it would scale
+            # every bf16 gradient by up to 0.2 % too much or little.
+            scale = None
             if tcfg.opt.grad_clip > 0:
                 flat_g, _ = jax.tree.flatten(grads)
                 sq = jnp.float32(0)
@@ -292,8 +296,6 @@ def make_train_step(model: Model, tcfg: TrainerConfig, param_specs,
                     sq = sq + ss
                 gn = jnp.sqrt(sq)
                 scale = jnp.minimum(1.0, tcfg.opt.grad_clip / (gn + 1e-9))
-                grads = jax.tree.map(lambda g: g * scale.astype(g.dtype),
-                                     grads)
                 metrics["grad_norm"] = gn
 
             # --- 4. parameter update ----------------------------------------
@@ -303,8 +305,9 @@ def make_train_step(model: Model, tcfg: TrainerConfig, param_specs,
             def leaf_update(p, g, st):
                 if tcfg.zero1:
                     return zero1_update(tcfg.opt, p, g, st, step, r, world,
-                                        zaxes)
-                return UPDATES[tcfg.opt.kind](tcfg.opt, p, g, st, step)
+                                        zaxes, scale)
+                return UPDATES[tcfg.opt.kind](tcfg.opt, p, _scaled(g, scale),
+                                              st, step)
 
             new_params, new_s = _zip_update(
                 params, grads, opt_state["leaves"], leaf_update)
@@ -323,10 +326,18 @@ def make_train_step(model: Model, tcfg: TrainerConfig, param_specs,
     return step_fn
 
 
-def zero1_update(opt: OptConfig, p, g, st, step, r, world: int, zaxes):
+def _scaled(g, scale):
+    """g times the clip factor, in float32 (g as it is without one)."""
+    return g if scale is None else g.astype(jnp.float32) * scale
+
+
+def zero1_update(opt: OptConfig, p, g, st, step, r, world: int, zaxes,
+                 scale=None):
     """ZeRO-1 update of one leaf: rank ``r`` owns rows [r*c0, (r+1)*c0) of
-    dim 0, ``st`` their moments; p and g keep their dtypes.  Zero rows pad
-    dim 0 where ``world`` does not divide it; their moments stay zero."""
+    dim 0, ``st`` their moments; p and g keep their dtypes, and the rank's
+    rows of g are multiplied by the clip factor ``scale`` (if any) in
+    float32.  Zero rows pad dim 0 where ``world`` does not divide it;
+    their moments stay zero."""
     shape = p.shape or (1,)
     c0 = opt_chunk_size(shape[0], world)
     pad = world * c0 - shape[0]
@@ -337,7 +348,8 @@ def zero1_update(opt: OptConfig, p, g, st, step, r, world: int, zaxes):
             x = jnp.pad(x, [(0, pad)] + [(0, 0)] * (len(shape) - 1))
         return lax.dynamic_slice_in_dim(x, r * c0, c0, 0)
 
-    p_new, st_new = UPDATES[opt.kind](opt, mine(p), mine(g), st, step)
+    p_new, st_new = UPDATES[opt.kind](opt, mine(p), _scaled(mine(g), scale),
+                                      st, step)
     if world > 1:
         with jax.named_scope(scopes.ZERO1_GATHER):
             p_new = lax.all_gather(p_new, zaxes, axis=0, tiled=True)
@@ -411,8 +423,9 @@ def cache_pspecs(model: Model) -> Any:
         if cfg.n_layers % cfg.shared_attn_every:
             out["ssm_tail"] = lift(ssm)
         out["attn"] = lift(attn)
-    elif cfg.mla_q_rank:
-        out["layers"] = lift(mla)
+    elif cfg.is_mla:
+        for key, _, _ in model.stacks():
+            out[key] = lift(mla)
     else:
         out["layers"] = lift(attn)
     if cfg.kind == "enc_dec":

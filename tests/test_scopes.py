@@ -17,9 +17,29 @@ from repro.analysis import scopes
     ("jit(step_fn)/shard_map/psum", "unscoped"),
     ("jit(step_fn)/jvp(fwd)/while/body/flash_fusable/exp", "fwd"),
     ("", "unscoped"),
+    # the expert layer's blocks leave the layers as they were
+    ("jit(step_fn)/jvp(fwd)/while/body/moe.route/sort", "fwd"),
+    ("jit(step_fn)/transpose(jvp(fwd))/while/body/moe.experts/pallas_call", "bwd"),
+    ("jit(step_fn)/transpose(jvp(fwd))/while/body/checkpoint/"
+     "rematted_computation/moe.experts/pallas_call", "remat"),
 ])
 def test_classify(op_name, layer):
     assert scopes.classify(op_name) == layer
+
+
+@pytest.mark.parametrize("op_name,block", [
+    ("jit(step_fn)/jvp(fwd)/while/body/moe.route/sort", "moe.route"),
+    ("jit(step_fn)/jvp(fwd)/while/body/moe.experts/pallas_call", "moe.experts"),
+    ("jit(step_fn)/transpose(jvp(fwd))/while/body/transpose(jvp(moe.experts))/"
+     "pallas_call", "moe.experts"),
+    ("jit(step_fn)/transpose(jvp(fwd))/while/body/checkpoint/"
+     "rematted_computation/moe.route/gather", "moe.route"),
+    ("jit(step_fn)/jvp(fwd)/while/body/flash_fusable/exp", "other"),
+    ("jit(step_fn)/shard_map/opt/mul", "other"),
+    ("", "other"),
+])
+def test_block_of(op_name, block):
+    assert scopes.block_of(op_name) == block
 
 
 def test_every_layer_is_named_once():
@@ -73,3 +93,11 @@ def test_instruction_scopes_fill_what_xla_left_unnamed():
     # in the entry computation a nameless copy is unscoped
     assert m["copy.9"] == "unscoped"
     assert set(m.values()) <= set(scopes.ALL)
+
+
+def test_instruction_blocks_cover_the_same_instructions():
+    b = scopes.instruction_blocks(HLO.replace("/while/body/add", "/while/body/moe.experts/add"))
+    assert set(b) == set(scopes.instruction_scopes(HLO))
+    assert b["add.2"] == "moe.experts"
+    # the loop's copy takes its while's block, which names none
+    assert b["copy.3"] == b["while.2"] == b["fusion.7"] == "other"

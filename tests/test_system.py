@@ -122,6 +122,30 @@ def test_zero1_equals_full_optimizer(mesh, request, dp, dtype):
                                       runs[f"False/p{i}"], err_msg=f"p{i}")
 
 
+
+@pytest.mark.parametrize("zero1", [True, False])
+def test_clipped_gradient_has_the_clip_norm(mesh, zero1):
+    """A clipped step hands AdamW a gradient whose global norm is the clip
+    norm to float32 rounding, in a bf16 model too: the factor is applied
+    in float32 (rounded to bf16 it would be off by up to 0.2 %)."""
+    cfg = dataclasses.replace(get_config("qwen2-0.5b").reduced(),
+                              dtype=jnp.bfloat16)
+    ocfg = OptConfig(lr=1e-3, grad_clip=1e-3)
+    prog = build_program(cfg, mesh, TrainerConfig(opt=ocfg, zero1=zero1))
+    attach_train(prog, seq_len=32, global_batch=4)
+    params = prog.init_params(0)
+    opt = prog.init_opt(params)
+    b = next(iter(SyntheticLM(cfg, DataConfig(seq_len=32, batch=4))))
+    _, opt, m = prog.train_step(params, opt,
+                                {k: jnp.asarray(v) for k, v in b.items()})
+    assert float(m["grad_norm"]) > 10 * ocfg.grad_clip
+    # after one step m = (1 - b1) g, in float32
+    moments = jax.tree.leaves(opt["leaves"],
+                              is_leaf=lambda x: isinstance(x, dict) and "m" in x)
+    gn = np.sqrt(sum(float(jnp.sum(jnp.square(s["m"] / (1 - ocfg.b1))))
+                     for s in moments))
+    assert gn == pytest.approx(ocfg.grad_clip, rel=1e-5)
+
 def test_all_sync_schemes_end_to_end(mesh):
     """Every baseline scheme runs as the trainer's gradient synchronizer
     (the Fig. 11/12 experiment is runnable, not just modeled)."""
